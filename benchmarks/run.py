@@ -11,8 +11,9 @@ Mapping to the paper:
   metadata_sync      Table 1: bytes globally replicated per rank (SFC) vs
                      diffusion, weak scaling
   migration_volume   Figs 8/9/11/13 data-migration stage: bytes moved per rank
-  lbm_mlups          kernel throughput (MLUPS, interpret-mode lower bound +
-                     pure-jnp reference path)
+  lbm_mlups          kernel throughput (MLUPS; the Pallas kernel runs in
+                     interpret mode on CPU and natively on a TPU) + the
+                     pure-jnp reference path
   stepping           per-substep restacking vs persistent arena vs the fused
                      device superstep vs the rank-sharded data plane (host
                      p2p + device-resident fused_sharded): blocks/s of the
@@ -34,6 +35,8 @@ import math
 import time
 
 import numpy as np
+
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _csv(name: str, metric: str, value) -> None:
@@ -171,8 +174,8 @@ def migration_volume(quick: bool = False) -> None:
 
 
 def lbm_mlups(quick: bool = False) -> None:
-    """Fused stream-collide throughput (CPU; TPU numbers come from the
-    roofline model — interpret-mode wall time is NOT the TPU projection)."""
+    """Fused stream-collide throughput. On CPU the Pallas kernel runs in
+    interpret mode, whose wall time says nothing about a TPU."""
     import jax
     import jax.numpy as jnp
 
@@ -187,7 +190,7 @@ def lbm_mlups(quick: bool = False) -> None:
     )
     mask = jnp.zeros((B, n, n, n), jnp.int32)
     for backend in ("ref", "pallas"):
-        step = make_stream_collide(omega=1.6, backend=backend, interpret=True)
+        step = make_stream_collide(omega=1.6, backend=backend)
         out = step(f, mask)
         out.block_until_ready()
         t0 = time.perf_counter()
@@ -582,6 +585,7 @@ def main() -> None:
              "per (mode, nranks) into this directory",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only or list(ALL)
     ranks = tuple(int(r) for r in args.ranks.split(",") if r)
     print("name,metric,value")

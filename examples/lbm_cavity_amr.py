@@ -19,6 +19,7 @@ per mode, data-plane halo traffic or host<->device transfer counts.
 
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lbm import AMRLBM, LidDrivenCavityConfig
 
 
@@ -36,6 +37,7 @@ def main() -> None:
     )
     ap.add_argument("--nranks", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = LidDrivenCavityConfig(
         root_grid=(2, 2, 2),

@@ -3,26 +3,26 @@
 TPU adaptation of the paper's compute hot loop (§3/§5: the D3Q19/D3Q27
 stream-collide accounts for nearly all FLOPs of the simulation):
 
-* The AMR domain partitioning already tiles the mesh into fixed-size blocks
-  (e.g. 34^3 cells incl. ghost layer, paper Fig. 16). One such block in f32
-  D3Q19 is ~3 MB — it fits VMEM whole. We therefore map **one AMR block per
-  Pallas grid step**: ``grid=(num_blocks,)`` with a full-block BlockSpec, so
-  each step runs entirely out of VMEM with a single HBM round-trip per
-  block, the optimum for this memory-bound kernel (AI ~ 1.5 flop/byte).
+* The AMR domain partitioning already tiles the mesh into fixed-size blocks.
+  We map **one AMR block per Pallas grid step**: ``grid=(num_blocks,)`` with
+  a full-block BlockSpec, so each step runs out of VMEM with a single HBM
+  round-trip per block (the kernel is memory-bound, AI ~ 1.5 flop/byte).
+  Whole-block tiles bound the block size. On a v5e an 18^3 tile (16^3 cells
+  plus the ghost layer, f32 D3Q19) needs about 16 MiB of scoped VMEM, so the
+  kernel raises the scoped limit to :data:`VMEM_LIMIT_BYTES` (32 MiB); a 34^3
+  tile (32^3 cells, the paper's Fig. 16 size) needs over 40 MiB, exceeds
+  both the default 16 MiB limit and this one, and is refused at compile
+  time.
 * Streaming is realized as static single-cell rolls of VMEM-resident planes
   (vector shifts on the VPU — no MXU work exists in LBM), fused with the
   collision so PDFs are read and written exactly once per time step.
-* The ghost layer travels with the block. The plain entry point
-  (:func:`lbm_stream_collide_pallas`) leaves halo exchange entirely to the
-  halo/driver layer; the halo-aware entry point
-  (:func:`lbm_stream_collide_halo_pallas`) additionally takes the block's
-  exchanged ghost values as a compact per-block operand and scatters them
-  into the VMEM-resident tile *before* streaming — the superstep no longer
-  materializes a separately exchanged full buffer between kernel calls.
+* The ghost layer travels with the block; the kernel leaves halo exchange
+  entirely to the halo/driver layer (the halo-aware superstep programs fill
+  ghosts with one XLA scatter right before calling it).
 
-The kernels are validated against ``ref.stream_collide_ref`` in interpret
-mode (this container is CPU-only); on TPU the same ``pallas_call`` lowers
-with the block resident in VMEM. Whether to interpret is resolved once at
+The kernel is validated against ``ref.stream_collide_ref`` in interpret
+mode on CPU; on TPU the same ``pallas_call`` lowers natively (Mosaic) with
+the block resident in VMEM. Whether to interpret is resolved once at
 program-build time from the active JAX backend (see :func:`resolve_interpret`).
 For best TPU layout the innermost (Z) extent should be padded to the
 128-lane width by the caller; correctness does not depend on it.
@@ -36,16 +36,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...lbm.lattice import D3Q19, Lattice
 from .ref import CT_FLUID, CT_LID
 
 __all__ = [
     "lbm_stream_collide_pallas",
-    "lbm_stream_collide_halo_pallas",
     "resolve_interpret",
     "resolve_donate",
 ]
+
+# Scoped-VMEM budget for one whole-block tile. An 18^3 f32 D3Q19 tile needs
+# just over 16 MiB (the default scoped limit) on a v5e once the grid has more
+# than a handful of blocks; the chip has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def resolve_interpret(interpret: bool | None = None) -> bool:
@@ -84,6 +89,15 @@ def resolve_donate(donate: bool | None = None) -> bool:
     return bool(donate)
 
 
+def _roll(a, shift: tuple[int, int, int]):
+    """``jnp.roll`` over the axes with a non-zero shift only: a zero shift
+    lowers to a zero-size slice, which Mosaic refuses."""
+    axes = tuple(i for i, s in enumerate(shift) if s)
+    if not axes:
+        return a
+    return jnp.roll(a, shift=tuple(shift[i] for i in axes), axis=axes)
+
+
 def _stream_collide_body(
     f,
     mask,
@@ -106,8 +120,8 @@ def _stream_collide_body(
     pulled = []
     for q in range(Q):
         sh = (int(c[q, 0]), int(c[q, 1]), int(c[q, 2]))
-        pulled.append(jnp.roll(f[q], shift=sh, axis=(0, 1, 2)))
-        is_fluid_src.append(jnp.roll(mask, shift=sh, axis=(0, 1, 2)))
+        pulled.append(_roll(f[q], sh))
+        is_fluid_src.append(_roll(mask, sh))
 
     f_in = []
     for q in range(Q):
@@ -175,30 +189,6 @@ def _kernel(f_ref, mask_ref, out_ref, **cfg):
     out_ref[0] = _stream_collide_body(f, mask, **cfg)
 
 
-def _halo_kernel(f_ref, mask_ref, hv_ref, hc_ref, hm_ref, out_ref, **cfg):
-    """Halo-aware variant: scatter the block's exchanged ghost values into
-    the VMEM tile, then stream+collide — the ghost gather is fused into the
-    stencil read instead of being materialized as a full exchanged buffer.
-
-    ``hv`` is the per-block padded (P, Q) ghost-value slab, ``hc`` the (P,)
-    flat cell ids in the ghosted box, ``hm`` the (P,) validity mask. Padding
-    rows all point at one interior cell that is never a halo target and
-    write back its current value, so the scatter has no conflicting
-    duplicate targets and padded entries are exact no-ops — the fill is
-    deterministic and bitwise equal to the unpadded jnp scatter."""
-    f = f_ref[0]
-    mask = mask_ref[0]
-    hv = hv_ref[0]  # (P, Q)
-    hc = hc_ref[0]  # (P,)
-    hm = hm_ref[0]  # (P,)
-    Q = f.shape[0]
-    flat = f.reshape(Q, -1)
-    cur = flat[:, hc]  # (Q, P)
-    new = jnp.where(hm[None, :], hv.T, cur)
-    f = flat.at[:, hc].set(new).reshape(f.shape)
-    out_ref[0] = _stream_collide_body(f, mask, **cfg)
-
-
 def _cfg(omega, lattice, u_wall, collision, magic):
     return dict(
         lattice=lattice,
@@ -241,63 +231,6 @@ def lbm_stream_collide_pallas(
         ],
         out_specs=pl.BlockSpec((1, Q, X, Y, Z), lambda b: (b, 0, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(f.shape, f.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
     )(f, mask)
-
-
-def lbm_stream_collide_halo_pallas(
-    f: jax.Array,
-    mask: jax.Array,
-    halo_vals: jax.Array,
-    halo_cell: jax.Array,
-    halo_valid: jax.Array,
-    *,
-    omega: float,
-    lattice: Lattice = D3Q19,
-    u_wall: tuple[float, float, float] = (0.0, 0.0, 0.0),
-    collision: str = "bgk",
-    magic: float = 3.0 / 16.0,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Halo-aware fused ghost-fill + stream+collide over a stack of blocks.
-
-    The kernel's tile effectively grows to cover the ghost ring: each grid
-    step receives, alongside its (Q, X, Y, Z) block, a compact padded slab of
-    the exchanged ghost values for that block and writes them into the tile
-    before the stencil reads — no intermediate exchanged buffer exists
-    between the gather and the stencil.
-
-    Args:
-      f:          (B, Q, X, Y, Z) post-collision PDFs (ghost layer included).
-      mask:       (B, X, Y, Z) int32 cell types.
-      halo_vals:  (B, P, Q) padded per-block ghost values (P = max fills per
-                  block; rows beyond a block's count are padding).
-      halo_cell:  (B, P) int32 flat cell ids into the ghosted (X, Y, Z) box;
-                  padding rows point at a never-targeted interior cell.
-      halo_valid: (B, P) bool; False rows are written back unchanged.
-      interpret:  None (default) resolves per :func:`resolve_interpret`.
-    Returns:
-      (B, Q, X, Y, Z) updated PDFs.
-    """
-    B, Q, X, Y, Z = f.shape
-    P = halo_cell.shape[1]
-    assert mask.shape == (B, X, Y, Z), (f.shape, mask.shape)
-    assert halo_vals.shape == (B, P, Q), (halo_vals.shape, (B, P, Q))
-    assert halo_valid.shape == (B, P), (halo_valid.shape, (B, P))
-    kern = functools.partial(
-        _halo_kernel, **_cfg(omega, lattice, u_wall, collision, magic)
-    )
-    return pl.pallas_call(
-        kern,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Q, X, Y, Z), lambda b: (b, 0, 0, 0, 0)),
-            pl.BlockSpec((1, X, Y, Z), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((1, P, Q), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, P), lambda b: (b, 0)),
-            pl.BlockSpec((1, P), lambda b: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Q, X, Y, Z), lambda b: (b, 0, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(f.shape, f.dtype),
-        interpret=resolve_interpret(interpret),
-    )(f, mask, halo_vals, halo_cell, halo_valid)

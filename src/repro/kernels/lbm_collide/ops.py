@@ -27,7 +27,6 @@ import numpy as np
 from ...lbm.halo import lower_halo_fill
 from ...lbm.lattice import D3Q19, Lattice
 from .lbm_collide import (
-    lbm_stream_collide_halo_pallas,
     lbm_stream_collide_pallas,
     resolve_donate,
     resolve_interpret,
@@ -146,40 +145,6 @@ def make_arena_stream_collide(
 # -- halo-in-tile stepping -------------------------------------------------------
 
 
-def _pad_fill_layout(
-    dst_slot: np.ndarray, dst_cell: np.ndarray, nblocks: int, dims: tuple[int, int, int]
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Repack a flat merged fill into the per-block padded slab layout the
-    halo-aware Pallas kernel consumes.
-
-    Returns ``(entry, cell, valid)``, each ``(nblocks, P)`` with ``P`` the
-    max fills per block: ``entry[b, j]`` indexes the fill's concatenated
-    value rows, ``cell[b, j]`` the flat ghosted-box cell to write. Padding
-    rows point at the box's center cell — an interior cell that is never a
-    halo target (all targets lie in the ghost ring) — with ``valid`` False,
-    so the kernel writes that cell's current value back: a deterministic
-    no-op even under duplicate-index scatter."""
-    n = int(dst_cell.size)
-    pad_cell = (dims[0] // 2 * dims[1] + dims[1] // 2) * dims[2] + dims[2] // 2
-    assert not np.any(dst_cell == pad_cell), "halo fill targeted the pad cell"
-    counts = np.bincount(dst_slot, minlength=nblocks)
-    assert counts.size == nblocks, (counts.size, nblocks)
-    P = int(counts.max()) if n else 0
-    entry = np.zeros((nblocks, P), dtype=np.int32)
-    cell = np.full((nblocks, P), pad_cell, dtype=np.int32)
-    valid = np.zeros((nblocks, P), dtype=bool)
-    order = np.argsort(dst_slot, kind="stable")
-    pos = 0
-    for b in range(nblocks):
-        k = int(counts[b])
-        idx = order[pos : pos + k]
-        pos += k
-        entry[b, :k] = idx
-        cell[b, :k] = dst_cell[idx]
-        valid[b, :k] = True
-    return entry, cell, valid
-
-
 def make_halo_stream_collide(
     dst_slot: np.ndarray,
     dst_cell: np.ndarray,
@@ -195,47 +160,35 @@ def make_halo_stream_collide(
 ):
     """Build a halo-aware ``step(f, vals) -> f`` for one level's block stack:
     the ghost fill targeting (``dst_slot``, ``dst_cell``) and the
-    stream+collide stencil run as one fused unit instead of materializing an
-    exchanged buffer between them.
+    stream+collide stencil run in one program, so no exchanged buffer is
+    materialized between substep programs.
 
     ``vals`` is the ``(N, Q)`` concatenated fill values (gathered by the
     enclosing superstep from pre-step buffers, in the merged fill's segment
-    order). On the ``pallas`` backend the fill happens *inside* the kernel:
-    each grid step scatters its block's padded value slab into the
-    VMEM-resident tile before the stencil reads. On the ``ref`` backend the
-    fill is a single merged jnp scatter feeding the stencil in the same
-    program — and the mask being a build-time constant here lets the
-    streaming selectors be precomputed on the host
+    order). The fill is a single merged XLA scatter on either backend; it
+    then feeds the Pallas kernel (``pallas``) or the jnp stencil (``ref``).
+    On ``ref`` the mask being a build-time constant lets the streaming
+    selectors be precomputed on the host
     (:func:`~.ref.precompute_stream_masks`), dropping the per-substep mask
-    rolls entirely. Both paths are bitwise equal to scatter-then-step.
+    rolls entirely.
 
     ``mask`` is the level's host ``(B, X, Y, Z)`` cell-type stack, closed
     over as a constant (programs are rebuilt on mask refresh / AMR events).
     """
     # repro: host-ok(build-time mask normalization, outside the stepping loop)
     mask = np.asarray(mask)
-    nblocks = mask.shape[0]
-    dims = mask.shape[1:]
     assert dst_cell.size > 0, "use make_stream_collide when there is no fill"
     db = jnp.asarray(dst_slot)
     dc = jnp.asarray(dst_cell)
 
     if backend == "pallas":
         interpret = resolve_interpret(interpret)
-        entry, cell, valid = _pad_fill_layout(dst_slot, dst_cell, nblocks, dims)
-        entry_j = jnp.asarray(entry)
-        cell_j = jnp.asarray(cell)
-        valid_j = jnp.asarray(valid)
         mask_j = jnp.asarray(mask)
 
-        def step(f: jax.Array, vals: jax.Array) -> jax.Array:
-            hv = vals[entry_j]  # (B, P, Q) padded per-block slabs
-            return lbm_stream_collide_halo_pallas(
+        def stencil(f: jax.Array) -> jax.Array:
+            return lbm_stream_collide_pallas(
                 f,
                 mask_j,
-                hv,
-                cell_j,
-                valid_j,
                 omega=omega,
                 lattice=lattice,
                 u_wall=u_wall,
@@ -250,8 +203,7 @@ def make_halo_stream_collide(
         ls = jnp.asarray(pm["lid_src"])
         fl = jnp.asarray(pm["fluid"])  # (B, X, Y, Z)
 
-        def step(f: jax.Array, vals: jax.Array) -> jax.Array:
-            f = _flat3(f).at[db, :, dc].set(vals).reshape(f.shape)
+        def stencil(f: jax.Array) -> jax.Array:
             coeffs = collision_coeffs(
                 omega,
                 lattice=lattice,
@@ -275,6 +227,9 @@ def make_halo_stream_collide(
 
     else:
         raise ValueError(f"unknown backend {backend!r}")
+
+    def step(f: jax.Array, vals: jax.Array) -> jax.Array:
+        return stencil(_flat3(f).at[db, :, dc].set(vals).reshape(f.shape))
 
     return step
 
@@ -961,8 +916,6 @@ def make_device_superstep(
         Masks are operands (not closed-over constants) because they are
         sharded alongside the pdfs.
     """
-    from jax.experimental.shard_map import shard_map  # noqa: PLC0415 — jax<0.5 has no jax.shard_map
-
     levels = tuple(sorted(levels))
     index = {l: i for i, l in enumerate(levels)}
     lmax = levels[-1]
@@ -1096,12 +1049,12 @@ def make_device_superstep(
         return tuple(b[None] for b in bufs)
 
     spec = jax.sharding.PartitionSpec(axis)
-    sm = shard_map(
+    sm = jax.shard_map(
         mapped,
         mesh=mesh,
         in_specs=(spec, spec),
         out_specs=spec,
-        check_rep=False,  # lax.switch on axis_index is deliberately per-device
+        check_vma=False,  # lax.switch on axis_index is deliberately per-device
     )
     if resolve_donate(donate):
         return jax.jit(sm, donate_argnums=0)

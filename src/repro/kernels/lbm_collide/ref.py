@@ -21,6 +21,7 @@ Non-fluid cells keep their PDF values unchanged.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -42,12 +43,17 @@ CT_FLUID = 0
 CT_WALL = 1
 CT_LID = 2
 
+# The moment contractions must be float32-exact: at default precision the TPU
+# runs an f32 dot as one bfloat16 pass, which would make the oracle less
+# accurate than the kernel it checks. CPU backends ignore the setting.
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def moments(f: jnp.ndarray, lattice: Lattice) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Density (X,Y,Z) and velocity (3,X,Y,Z) from PDFs (Q,X,Y,Z)."""
     c = jnp.asarray(lattice.c, dtype=f.dtype)  # (Q,3)
     rho = jnp.sum(f, axis=0)
-    mom = jnp.einsum("qxyz,qd->dxyz", f, c)
+    mom = jnp.einsum("qxyz,qd->dxyz", f, c, precision=_F32)
     u = mom / rho[None]
     return rho, u
 
@@ -56,7 +62,7 @@ def equilibrium(rho: jnp.ndarray, u: jnp.ndarray, lattice: Lattice) -> jnp.ndarr
     """Second-order Maxwell equilibrium, shape (Q, X, Y, Z)."""
     c = jnp.asarray(lattice.c, dtype=rho.dtype)  # (Q,3)
     w = jnp.asarray(lattice.w, dtype=rho.dtype)  # (Q,)
-    cu = jnp.einsum("qd,dxyz->qxyz", c, u)  # (Q,X,Y,Z)
+    cu = jnp.einsum("qd,dxyz->qxyz", c, u, precision=_F32)  # (Q,X,Y,Z)
     usq = jnp.sum(u * u, axis=0)  # (X,Y,Z)
     return w[:, None, None, None] * rho[None] * (
         1.0 + 3.0 * cu + 4.5 * cu * cu - 1.5 * usq[None]

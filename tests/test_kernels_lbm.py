@@ -45,7 +45,7 @@ def test_pallas_matches_ref(lattice, collision, shape):
 def test_pallas_dtype_sweep(dtype):
     import jax
 
-    with jax.experimental.enable_x64(True) if dtype == np.float64 else _null():
+    with jax.enable_x64(True) if dtype == np.float64 else _null():
         rng = np.random.default_rng(7)
         f, mask = _random_state(rng, 1, D3Q19, (6, 6, 6), dtype)
         kw = dict(omega=1.2, lattice=D3Q19, collision="bgk")

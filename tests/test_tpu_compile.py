@@ -1,0 +1,84 @@
+"""Compile the main path's Pallas programs for a described TPU v5e.
+
+Nothing runs: the TPU compiler installed with JAX compiles for a chip that is
+described, not attached, and refuses what the chip would refuse (unaligned
+block shapes, zero-size slices, too much scoped VMEM). Interpret-mode tests
+cannot see any of that. Sizes are the real ones: 16^3 cells per block (18^3
+tiles with the ghost layer), D3Q19, TRT, 64 blocks per call.
+
+The topology is described inside a module fixture, never at import: only one
+process may load the TPU library, and test workers must all collect the same
+tests.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.lbm_collide.lbm_collide import lbm_stream_collide_pallas
+from repro.kernels.lbm_collide.ops import make_halo_stream_collide
+from repro.kernels.lbm_collide.ref import CT_LID, CT_WALL
+from repro.lbm.lattice import D3Q19
+
+B, N = 64, 18  # blocks per call, tile edge (16 cells + 2 ghost)
+KW = dict(omega=1.5, lattice=D3Q19, u_wall=(0.08, 0.0, 0.0), collision="trt")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _mask() -> np.ndarray:
+    mask = np.zeros((B, N, N, N), np.int32)
+    mask[:, 0] = CT_WALL
+    mask[:, :, 0] = CT_WALL
+    mask[:, :, :, -1] = CT_LID
+    return mask
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_plain_stencil_compiles_natively(one_chip):
+    step = jax.jit(
+        lambda f, m: lbm_stream_collide_pallas(f, m, interpret=False, **KW)
+    )
+    compiled = step.lower(
+        _sds((B, D3Q19.Q, N, N, N), jnp.float32, one_chip),
+        _sds((B, N, N, N), jnp.int32, one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_halo_in_tile_step_compiles_natively(one_chip):
+    # ghost targets: the whole x = 0 face of every block
+    cells = np.arange(N * N, dtype=np.int32)
+    dst_slot = np.repeat(np.arange(B, dtype=np.int32), cells.size)
+    dst_cell = np.tile(cells, B)
+    step = make_halo_stream_collide(
+        dst_slot, dst_cell, mask=_mask(), backend="pallas", interpret=False, **KW
+    )
+    compiled = (
+        jax.jit(step)
+        .lower(
+            _sds((B, D3Q19.Q, N, N, N), jnp.float32, one_chip),
+            _sds((dst_cell.size, D3Q19.Q), jnp.float32, one_chip),
+        )
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
