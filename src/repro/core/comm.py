@@ -105,7 +105,6 @@ class Comm:
         self.stats.sent_bytes_by_rank[src] += nbytes
         if _TR.enabled:
             _TR.metrics.counter("comm.p2p_bytes").inc(nbytes, src=src, dst=dst)
-            _TR.metrics.counter("comm.p2p_messages").inc(src=src, dst=dst)
             _TR.metrics.histogram(
                 "comm.p2p_message_bytes", buckets=BYTES_BUCKETS
             ).observe(nbytes)
@@ -135,8 +134,6 @@ class Comm:
         self.stats.allreduce_calls += 1
         self.stats.rounds += max(1, (self.nranks - 1).bit_length())
         self.stats.collective_bytes_per_rank += nbytes
-        if _TR.enabled:
-            _TR.metrics.counter("comm.collectives").inc(kind="allreduce")
         it = iter(per_rank_values)
         acc = next(it)
         for v in it:
@@ -152,8 +149,6 @@ class Comm:
         self.stats.allgather_calls += 1
         self.stats.rounds += max(1, (self.nranks - 1).bit_length())
         self.stats.collective_bytes_per_rank += nbytes_each * self.nranks
-        if _TR.enabled:
-            _TR.metrics.counter("comm.collectives").inc(kind="allgather")
         return list(per_rank_values)
 
     def barrier(self) -> None:
@@ -206,9 +201,6 @@ class DeviceComm(Comm):
             if _TR.enabled:
                 _TR.metrics.counter("comm.p2p_bytes").inc(
                     m.nbytes, src=m.src_rank, dst=m.dst_rank
-                )
-                _TR.metrics.counter("comm.p2p_messages").inc(
-                    src=m.src_rank, dst=m.dst_rank
                 )
                 _TR.metrics.histogram(
                     "comm.p2p_message_bytes", buckets=BYTES_BUCKETS

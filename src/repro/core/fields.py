@@ -475,7 +475,8 @@ class DeviceResidency:
         Returns:
             The ``jax.Array`` mirror of ``arena.buffer(level, name)``. If no
             device copy exists (first access, or everything was dropped by a
-            version bump) the host buffer is uploaded and the transfer
+            version bump) the host buffer is uploaded inside a
+            ``residency:upload`` stage carrying its bytes, and the transfer
             counted; otherwise the cached array — possibly a device-newer
             one installed by :meth:`store` — is returned with no transfer.
 
@@ -492,15 +493,12 @@ class DeviceResidency:
         if arr is None:
             host = self.arena.buffer(level, name)
             assert host is not None, f"no arena buffer for L{level} {name!r}"
-            arr = jnp.asarray(host)
+            with _TR.stage("upload", cat="residency", rank=self.arena.rank or 0,
+                           level=level, field=name, bytes=host.nbytes):
+                arr = jnp.asarray(host)
             self._dev[key] = arr
             self.h2d_transfers += 1
             self.h2d_bytes += host.nbytes
-            if _TR.enabled:
-                _TR.instant(
-                    "h2d", cat="residency", rank=self.arena.rank or 0,
-                    level=level, field=name, bytes=host.nbytes,
-                )
         return arr
 
     def store(self, level: int, name: str, value) -> None:
@@ -567,25 +565,28 @@ class DeviceResidency:
         """Materialize host views: download every device-newer buffer into
         its arena storage in place (block views stay bound).
 
-        Downloads are counted (``d2h_transfers`` / ``d2h_bytes``) and the
-        device-newer marks cleared; the device copies are kept and remain
-        *synced*, so a later :meth:`fetch` performs no re-upload. Idempotent:
+        The downloads run inside one ``residency:flush`` stage carrying
+        their bytes (no stage when nothing is pending) and are counted
+        (``d2h_transfers`` / ``d2h_bytes``); the device-newer marks are
+        cleared and the device copies kept, *synced*, so a later
+        :meth:`fetch` performs no re-upload. Idempotent:
         a second flush with nothing pending transfers nothing — the
         conformance suite relies on this to pin "transfers only when state
         actually moved".
         """
         self._sync_version()
-        for key in sorted(self._dev_newer):
-            level, name = key
-            host = self.arena.buffer(level, name)
-            np.copyto(host, np.asarray(self._dev[key]))
-            self.d2h_transfers += 1
-            self.d2h_bytes += host.nbytes
-            if _TR.enabled:
-                _TR.instant(
-                    "d2h", cat="residency", rank=self.arena.rank or 0,
-                    level=level, field=name, bytes=host.nbytes,
-                )
+        if not self._dev_newer:
+            return
+        nbytes = 0
+        with _TR.stage("flush", cat="residency", rank=self.arena.rank or 0) as sp:
+            for key in sorted(self._dev_newer):
+                level, name = key
+                host = self.arena.buffer(level, name)
+                np.copyto(host, np.asarray(self._dev[key]))
+                nbytes += host.nbytes
+            sp.set(bytes=nbytes)
+        self.d2h_transfers += len(self._dev_newer)
+        self.d2h_bytes += nbytes
         self._dev_newer.clear()
 
 

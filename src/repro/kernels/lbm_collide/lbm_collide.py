@@ -222,6 +222,11 @@ def lbm_stream_collide_pallas(
     B, Q, X, Y, Z = f.shape
     assert mask.shape == (B, X, Y, Z), (f.shape, mask.shape)
     kern = functools.partial(_kernel, **_cfg(omega, lattice, u_wall, collision, magic))
+    # a stable name for the kernel's custom call in every program that calls
+    # it; profile events carry the whole HLO instruction, operands included,
+    # so the name must not also be one the trace reduction looks for in the
+    # text (``stream_collide``), or every op reading the kernel's output
+    # would count as kernel time
     return pl.pallas_call(
         kern,
         grid=(B,),
@@ -233,4 +238,5 @@ def lbm_stream_collide_pallas(
         out_shape=jax.ShapeDtypeStruct(f.shape, f.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
+        name="lbm_collide",
     )(f, mask)

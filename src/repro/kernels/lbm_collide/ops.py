@@ -149,6 +149,7 @@ def make_halo_stream_collide(
     dst_slot: np.ndarray,
     dst_cell: np.ndarray,
     *,
+    level: int,
     mask: np.ndarray,
     omega: float,
     lattice: Lattice = D3Q19,
@@ -174,6 +175,8 @@ def make_halo_stream_collide(
 
     ``mask`` is the level's host ``(B, X, Y, Z)`` cell-type stack, closed
     over as a constant (programs are rebuilt on mask refresh / AMR events).
+    ``level`` names the fill scatter ``ghost_fill/scatter/L<level>`` in the
+    program's op metadata.
     """
     # repro: host-ok(build-time mask normalization, outside the stepping loop)
     mask = np.asarray(mask)
@@ -229,7 +232,9 @@ def make_halo_stream_collide(
         raise ValueError(f"unknown backend {backend!r}")
 
     def step(f: jax.Array, vals: jax.Array) -> jax.Array:
-        return stencil(_flat3(f).at[db, :, dc].set(vals).reshape(f.shape))
+        with jax.named_scope(f"ghost_fill/scatter/L{level}"):
+            f = _flat3(f).at[db, :, dc].set(vals).reshape(f.shape)
+        return stencil(f)
 
     return step
 
@@ -296,10 +301,15 @@ def _lower_fill_gathers(fill, level_index: dict[int, int]) -> tuple:
     )
 
 
-def _concat_vals(bufs, gathers, extra=()) -> jax.Array:
+def _concat_vals(bufs, gathers, level: int, extra=()) -> jax.Array:
     """Concatenate gathered segment values (plus any pre-built extra value
-    arrays, e.g. inbound message slices) in merged-fill order."""
-    parts = [_gather_vals(bufs[si], kind, sb, sc) for si, kind, sb, sc in gathers]
+    arrays, e.g. inbound message slices) in merged-fill order for the fill of
+    ``level``. Each segment's gather is named ``ghost_fill/<kind>/L<level>``
+    in the program's op metadata, so a profile can tell the fills apart."""
+    parts = []
+    for si, kind, sb, sc in gathers:
+        with jax.named_scope(f"ghost_fill/{kind}/L{level}"):
+            parts.append(_gather_vals(bufs[si], kind, sb, sc))
     parts += list(extra)
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
@@ -414,7 +424,7 @@ def make_fused_superstep(
             bufs = list(pdfs)
             # all fill values gather from the pre-step buffers (every source
             # is an interior cell; every target a ghost cell — disjoint)
-            vals = {l: _concat_vals(bufs, gathers[l]) for l in fills}
+            vals = {l: _concat_vals(bufs, gathers[l], l) for l in fills}
             for l in active:  # finest first
                 i = index[l]
                 if l in fills:
@@ -554,7 +564,7 @@ def make_ensemble_superstep(
             bufs = list(pdfs)
             # all fill values gather from the pre-step buffers, exactly as
             # the solo fused superstep's halo-in-tile branch does
-            vals = {l: _concat_vals(bufs, gathers[l]) for l in fills}
+            vals = {l: _concat_vals(bufs, gathers[l], l) for l in fills}
             for l in active:  # finest first, matching the solo kernel order
                 i = index[l]
                 if l in fills:
@@ -738,6 +748,7 @@ def make_rank_absorb(
                 l: _concat_vals(
                     bufs,
                     e["gath"],
+                    l,
                     extra=[msgs[mi][off : off + n] for mi, off, n in e["msg"]],
                 )
                 for l, e in per.items()
@@ -838,7 +849,7 @@ def make_rank_absorb_split(
         # every local fill (interior *and* boundary targets) gathers and
         # lands here, from pre-step sources
         for l, (db, dc, gath) in local_j.items():
-            vals = _concat_vals(bufs, gath)
+            vals = _concat_vals(bufs, gath, l)
             i = level_index[l]
             d = bufs[i]
             bufs[i] = _flat3(d).at[db, :, dc].set(vals).reshape(d.shape)

@@ -341,7 +341,12 @@ class AMRLBM:
 
     # -- AMR ------------------------------------------------------------------
     def adapt(self, force_rebalance: bool = False):
-        """Evaluate the refinement criterion and run one AMR cycle."""
+        """Evaluate the refinement criterion and run one AMR cycle.
+
+        The engine's rebind after an executed cycle is timed (and mirrored
+        into the profiler) as three ``rebind`` stages: ``adopt`` (storage
+        repack and cache invalidation), ``masks`` and ``ghosts``; the flush
+        before the pipeline is the residency's own ``flush`` stage."""
         self.materialize_host()  # criterion + migration read host views
         self.forest, report = self.pipeline.run_cycle(
             self.forest, self.comm, self.criterion, force_rebalance=force_rebalance
@@ -352,10 +357,13 @@ class AMRLBM:
                 "amr.event", cat="amr", cycle=self.amr_cycles,
                 blocks=self.forest.num_blocks(),
             )
-            self.engine.adopt(self.forest)  # repack/rebuild storage, rebind views
-            self.engine.sync_caches()
-            self.refresh_masks()
-            self.engine.exchange_ghosts()
+            with _TR.stage("adopt", cat="rebind"):
+                self.engine.adopt(self.forest)  # repack/rebuild storage, rebind views
+                self.engine.sync_caches()
+            with _TR.stage("masks", cat="rebind"):
+                self.refresh_masks()
+            with _TR.stage("ghosts", cat="rebind"):
+                self.engine.exchange_ghosts()
         return report
 
     def run(self, coarse_steps: int, amr_interval: int = 4) -> None:

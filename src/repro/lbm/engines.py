@@ -48,7 +48,7 @@ import numpy as np
 
 from ..core import LevelArena, RankArenas
 from ..core.pipeline import StageStats
-from ..telemetry import get_tracer
+from ..telemetry import NULL_SPAN, get_tracer
 from ..kernels.lbm_collide.ops import (
     boundary_slot_sets,
     make_arena_stream_collide,
@@ -112,6 +112,7 @@ class StepEngine:
         # adoptions (restack rebinds arrays per substep, so no caching there)
         self._halo_plans: dict | None = {}
         self._cache_version = -1  # last storage version the caches were built for
+        self._program_fresh = False  # a device program was built, not yet called
 
     # -- kernel steppers -------------------------------------------------------
     stepper_factory = staticmethod(make_arena_stream_collide)
@@ -137,7 +138,8 @@ class StepEngine:
 
         def factory(level: int, dst_slot: np.ndarray, dst_cell: np.ndarray):
             return make_halo_stream_collide(
-                dst_slot, dst_cell, mask=masks_host[level], **self._stepper_kwargs(level)
+                dst_slot, dst_cell, level=level, mask=masks_host[level],
+                **self._stepper_kwargs(level),
             )
 
         return factory
@@ -156,6 +158,15 @@ class StepEngine:
                 **self._stepper_kwargs(level)
             )
         return self._fused_steppers[level]
+
+    def _first_call(self):
+        """The ``compile:superstep`` stage around the first call of a device
+        program the engine has just built (its trace, compile or cache load,
+        and dispatch); the shared no-op span on every later call."""
+        if not self._program_fresh:
+            return NULL_SPAN
+        self._program_fresh = False
+        return _TR.stage("superstep", cat="compile")
 
     # -- storage / invalidation ------------------------------------------------
     def storage_version(self) -> int:
@@ -367,6 +378,7 @@ class FusedEngine(ArenaEngine):
                 halo_stepper_factory=self._halo_stepper_factory(masks_host),
             )
         self._fused_key = key
+        self._program_fresh = True
         return self._fused_fn, levels
 
     def advance(self, coarse_steps: int) -> None:
@@ -382,7 +394,8 @@ class FusedEngine(ArenaEngine):
         nsub = 1 << levels[-1]
         with _TR.stage("fused", cat="stage", coarse_steps=coarse_steps) as sp:
             for _ in range(coarse_steps):
-                pdfs = fn(pdfs)
+                with self._first_call():
+                    pdfs = fn(pdfs)
             # repro: host-ok(timing fence: StageStats seconds must not hide queued device work)
             jax.block_until_ready(pdfs)
             for l, arr in zip(levels, pdfs):
@@ -852,6 +865,7 @@ class DeviceShardedEngine(ShardedEngine):
                       version=self.arenas.version):
             self._dev_programs = self._build_programs(forest, levels)
         self._dev_programs_key = key
+        self._program_fresh = True
         return self._dev_programs
 
     def _build_programs(self, forest: "BlockForest",
@@ -984,7 +998,7 @@ class DeviceShardedEngine(ShardedEngine):
             pdfs = self._dev_pdfs
             for _ in range(coarse_steps):
                 with _TR.span("device_superstep", cat="substep",
-                              nsub=progs.nsub):
+                              nsub=progs.nsub), self._first_call():
                     pdfs = progs.fn(pdfs, self._dev_masks)
                 for s in range(progs.nsub):
                     p = progs.pattern[s]

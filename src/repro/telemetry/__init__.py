@@ -18,10 +18,15 @@ observability):
   context manager when tracing is off; ``stage()`` always times (it replaces
   the hand-rolled ``perf_counter``/``StageStats`` idiom) but records
   nothing. An overhead test pins the disabled path.
-* **One clock.** All timestamps come from the tracer's injectable ``clock``
-  (default ``time.perf_counter``), so latency tests can substitute a fake
-  clock and every ``StageStats.seconds`` is derivable from the spans that
-  produced it — the two surfaces cannot disagree.
+* **One set of spans, on the profiler's clock too.** Every span that is
+  entered (each ``stage()``, and ``span()`` when enabled) also enters a
+  ``jax.profiler.TraceAnnotation`` named ``<cat>:<name>``, so a profiler
+  trace shows the same spans as the Chrome export, on the profiler's own
+  clock beside the device ops. The recorded timestamps and durations come
+  from the tracer's injectable ``clock`` (default ``time.perf_counter``), so
+  latency tests can substitute a fake clock and every ``StageStats.seconds``
+  is derivable from the spans that produced it — the two surfaces cannot
+  disagree.
 
 Usage::
 

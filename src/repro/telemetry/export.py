@@ -8,10 +8,11 @@ https://ui.perfetto.dev):
 * **tid = subsystem.** Each span category ("amr", "stage", "substep",
   "halo.plan", "compile", "residency", "serving", ...) gets one thread per
   process, named accordingly.
-* **Counter tracks.** Events carrying a ``bytes`` arg (residency h2d/d2h,
-  route payloads) accumulate into per-(rank, category) byte counter tracks;
-  ``compile``-category events accumulate into a compile-count track — the
-  bytes/compiles timelines the paper-style breakdowns read.
+* **Counter tracks.** Events carrying a ``bytes`` arg (residency uploads
+  and flushes, route payloads) accumulate into per-(rank, category) byte
+  counter tracks; ``compile``-category events accumulate into a
+  compile-count track — the bytes/compiles timelines the paper-style
+  breakdowns read.
 
 The trace also embeds the bounded metrics snapshot and per-rank ring
 accounting under ``"metadata"`` so ``tools/trace_report.py`` can render
@@ -30,7 +31,6 @@ __all__ = [
     "to_chrome_trace",
     "write_chrome_trace",
     "stage_seconds",
-    "stage_totals",
 ]
 
 TRACE_VERSION = 1
@@ -137,17 +137,4 @@ def stage_seconds(tracer: Tracer | None = None, *, cat: str = "stage") -> dict[s
     for rec in tr.records():
         if rec.ph == "X" and rec.cat == cat:
             out[rec.name] = out.get(rec.name, 0.0) + rec.dur
-    return out
-
-
-def stage_totals(tracer: Tracer | None = None) -> dict[tuple[str, str], dict]:
-    """(cat, name) -> {count, seconds} over every recorded span."""
-    tr = tracer if tracer is not None else get_tracer()
-    out: dict[tuple[str, str], dict] = {}
-    for rec in tr.records():
-        if rec.ph != "X":
-            continue
-        agg = out.setdefault((rec.cat, rec.name), {"count": 0, "seconds": 0.0})
-        agg["count"] += 1
-        agg["seconds"] += rec.dur
     return out

@@ -14,6 +14,10 @@ The contracts pinned here are the ones the observability layer advertises:
   :func:`~repro.telemetry.export.stage_seconds` accumulates in recording
   order, so the span sums equal the ``data_stats`` / ``CycleReport``
   surfaces *exactly* (float-for-float), and the two can never disagree.
+* **On the profiler's clock** — every ``stage()`` reaches a
+  ``jax.profiler`` trace's host plane as ``<cat>:<name>``, with telemetry
+  off too, while a disabled ``span()`` leaves nothing; an AMR cycle's flush
+  and the engine rebind's three parts appear there once each.
 * **Valid traces** — a traced 4-rank ``fused_sharded`` run spanning an AMR
   event exports Chrome-trace JSON that ``tools/trace_report.py`` accepts,
   including the per-substep emit/interior/route/absorb phases that make the
@@ -163,14 +167,90 @@ def test_disabled_path_is_null_and_records_nothing():
 
 def test_disabled_span_overhead_is_negligible():
     """Pin the cost of leaving instrumentation in hot loops: 100k disabled
-    span() round-trips must be far below anything a stepping loop notices
-    (generous wall bound to stay robust on loaded CI hosts)."""
+    span() round-trips must be far below anything a stepping loop notices,
+    and so must 100k stage() round-trips, which time and enter a profiler
+    annotation even when disabled (generous wall bounds to stay robust on
+    loaded CI hosts; stages sit at layer boundaries, a few per coarse step
+    or AMR cycle)."""
     tr = Tracer(enabled=False)
     t0 = time.perf_counter()
     for _ in range(100_000):
         with tr.span("hot", cat="substep", rank=0):
             pass
     assert time.perf_counter() - t0 < 1.0
+    t0 = time.perf_counter()
+    for _ in range(100_000):
+        with tr.stage("hot", cat="stage", rank=0):
+            pass
+    assert time.perf_counter() - t0 < 2.0
+    assert tr.records() == []
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled_host_spans(out_dir: Path, fn) -> list[str]:
+    """Run ``fn`` under a profiler session of this process and return the
+    names of the host plane's events, in order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(out_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = sorted(out_dir.rglob("*.xplane.pb"))[-1:]
+    names = []
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                names.extend((ev.start_ns, ev.name) for ev in line.events)
+    return [n for _t, n in sorted(names)]
+
+
+@pytest.mark.parametrize("kind, mirrored", [("stage", 1), ("span", 0)])
+def test_disabled_tracer_mirrors_stages_and_not_spans(tmp_path, kind, mirrored):
+    """With telemetry off a ``stage()`` still reaches the profiler's host
+    plane as ``<cat>:<name>`` (it times anyway); a ``span()`` is the shared
+    no-op and leaves no trace. Neither records."""
+    tr = Tracer(enabled=False)
+
+    def work():
+        with getattr(tr, kind)("probe", cat="probe"):
+            time.sleep(1e-3)
+
+    names = _profiled_host_spans(tmp_path, work)
+    assert names.count("probe:probe") == mirrored
+    assert tr.records() == []
+
+
+def test_amr_rebind_spans_reach_profiler(tmp_path):
+    """One executed AMR cycle of a fused cavity shows the flush before the
+    pipeline and the engine rebind's three parts once each on the
+    profiler's host plane, with telemetry off; the next coarse step
+    uploads the new stacks and compiles the new superstep under spans of
+    their own."""
+    sim = AMRLBM(_cfg(stepping_mode="fused", nranks=1))
+    sim.advance(1)
+    reports = []
+
+    def work():
+        reports.append(sim.adapt(force_rebalance=True))
+        sim.advance(1)
+
+    names = _profiled_host_spans(tmp_path, work)
+    assert reports[0].executed
+    for name in ("residency:flush", "rebind:adopt", "rebind:masks", "rebind:ghosts",
+                 "compile:superstep"):
+        assert names.count(name) == 1, (name, names.count(name))
+    for stage in ("refine", "proxy", "balance", "migrate"):
+        assert names.count(f"amr:{stage}") == 1
+    assert names.count("stage:halo") == 1  # nested in rebind:ghosts
+    uploads = [i for i, n in enumerate(names) if n == "residency:upload"]
+    assert uploads and min(uploads) > names.index("rebind:ghosts")
 
 
 # ---------------------------------------------------------------------------

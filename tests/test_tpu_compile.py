@@ -71,7 +71,7 @@ def test_halo_in_tile_step_compiles_natively(one_chip):
     dst_slot = np.repeat(np.arange(B, dtype=np.int32), cells.size)
     dst_cell = np.tile(cells, B)
     step = make_halo_stream_collide(
-        dst_slot, dst_cell, mask=_mask(), backend="pallas", interpret=False, **KW
+        dst_slot, dst_cell, level=0, mask=_mask(), backend="pallas", interpret=False, **KW
     )
     compiled = (
         jax.jit(step)
@@ -82,3 +82,26 @@ def test_halo_in_tile_step_compiles_natively(one_chip):
         .compile()
     )
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_halo_in_tile_step_names_its_fill_and_kernel(one_chip):
+    """The compiled step's ops carry the ghost fill's scope in their
+    ``op_name`` metadata, and the kernel's custom call has a stable name,
+    so a profile read against the HLO can tell the fill from the kernel."""
+    cells = np.arange(N * N, dtype=np.int32)
+    step = make_halo_stream_collide(
+        np.repeat(np.arange(B, dtype=np.int32), cells.size), np.tile(cells, B),
+        level=1, mask=_mask(), backend="pallas", interpret=False, **KW
+    )
+    text = (
+        jax.jit(step)
+        .lower(
+            _sds((B, D3Q19.Q, N, N, N), jnp.float32, one_chip),
+            _sds((B * cells.size, D3Q19.Q), jnp.float32, one_chip),
+        )
+        .compile()
+        .as_text()
+    )
+    assert 'op_name="jit(step)/ghost_fill/scatter/L1/' in text
+    kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert kernel and all(ln.lstrip().startswith("%lbm_collide") for ln in kernel)
