@@ -24,8 +24,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...lbm.halo import lower_halo_fill
+from ...lbm.halo import (
+    SLAB_DIRECTIONS,
+    lower_halo_fill,
+    lower_slab_placement,
+    slab_slices,
+    split_halo_fill,
+)
 from ...lbm.lattice import D3Q19, Lattice
+from ...telemetry import get_tracer
 from .lbm_collide import (
     lbm_stream_collide_pallas,
     resolve_donate,
@@ -37,6 +44,8 @@ from .ref import (
     stream_collide_coeffs,
     stream_collide_ref,
 )
+
+_TR = get_tracer()
 
 __all__ = [
     "fused_stream_collide",
@@ -158,6 +167,7 @@ def make_halo_stream_collide(
     magic: float = 3.0 / 16.0,
     backend: str = "pallas",
     interpret: bool | None = None,
+    slabs=None,
 ):
     """Build a halo-aware ``step(f, vals) -> f`` for one level's block stack:
     the ghost fill targeting (``dst_slot``, ``dst_cell``) and the
@@ -166,8 +176,13 @@ def make_halo_stream_collide(
 
     ``vals`` is the ``(N, Q)`` concatenated fill values (gathered by the
     enclosing superstep from pre-step buffers, in the merged fill's segment
-    order). The fill is a single merged XLA scatter on either backend; it
-    then feeds the Pallas kernel (``pallas``) or the jnp stencil (``ref``).
+    order). Without ``slabs`` the fill is a single merged XLA scatter. With
+    ``slabs`` (a :class:`~repro.lbm.halo.LevelSlabFill`) the ghost slabs of
+    same-level neighbours are block-granular copies read from ``f`` itself,
+    ``vals`` fills only the remaining targets (``None`` when there are
+    none), and the stack is assembled in one pass (:func:`_halo_fill`).
+    The filled stack feeds the Pallas kernel (``pallas``) or the jnp stencil
+    (``ref``).
     On ``ref`` the mask being a build-time constant lets the streaming
     selectors be precomputed on the host
     (:func:`~.ref.precompute_stream_masks`), dropping the per-substep mask
@@ -175,14 +190,22 @@ def make_halo_stream_collide(
 
     ``mask`` is the level's host ``(B, X, Y, Z)`` cell-type stack, closed
     over as a constant (programs are rebuilt on mask refresh / AMR events).
-    ``level`` names the fill scatter ``ghost_fill/scatter/L<level>`` in the
+    ``level`` names the fill's ops ``ghost_fill/slab/L<level>`` (slab
+    copies) and ``ghost_fill/scatter/L<level>`` (per-cell values) in the
     program's op metadata.
     """
     # repro: host-ok(build-time mask normalization, outside the stepping loop)
     mask = np.asarray(mask)
-    assert dst_cell.size > 0, "use make_stream_collide when there is no fill"
-    db = jnp.asarray(dst_slot)
-    dc = jnp.asarray(dst_cell)
+    assert dst_cell.size > 0 or slabs is not None, (
+        "use make_stream_collide when there is no fill"
+    )
+    _count_fill_cells(level, slabs, dst_cell.size)
+    if slabs is None:
+        targets, placement = (jnp.asarray(dst_slot), jnp.asarray(dst_cell)), None
+    else:
+        targets, placement = None, lower_slab_placement(
+            dst_slot, dst_cell, slabs.cells, slabs.ghost, mask.shape[0]
+        )
 
     if backend == "pallas":
         interpret = resolve_interpret(interpret)
@@ -231,12 +254,97 @@ def make_halo_stream_collide(
     else:
         raise ValueError(f"unknown backend {backend!r}")
 
-    def step(f: jax.Array, vals: jax.Array) -> jax.Array:
-        with jax.named_scope(f"ghost_fill/scatter/L{level}"):
-            f = _flat3(f).at[db, :, dc].set(vals).reshape(f.shape)
-        return stencil(f)
+    def step(f: jax.Array, vals: jax.Array | None) -> jax.Array:
+        return stencil(_halo_fill(f, vals, level, targets, slabs, placement))
 
     return step
+
+
+def _count_fill_cells(level: int, slabs, n_scatter: int) -> None:
+    """Record, per built fill, how many ghost cells the slab copies fill and
+    how many the per-cell scatter fills (``halo.slab_cells`` and
+    ``halo.scatter_cells``, labelled by level)."""
+    if not _TR.enabled:
+        return
+    n_slab = 0 if slabs is None else slabs.num_cells
+    _TR.metrics.counter("halo.slab_cells").inc(n_slab, level=level)
+    _TR.metrics.counter("halo.scatter_cells").inc(n_scatter, level=level)
+
+
+def _halo_fill(f: jax.Array, vals, level: int, targets, slabs, placement) -> jax.Array:
+    """One level's ghost fill. Without a slab part: the merged per-cell
+    scatter of ``vals`` to ``targets`` (``(dst_slot, dst_cell)`` device
+    arrays). With one: the same-level slabs copied block by block, ``vals``
+    (if any) written cell by cell into the slabs by ``placement``
+    (:func:`~repro.lbm.halo.lower_slab_placement`), and the stack assembled
+    in one pass, with no scatter."""
+    if slabs is None:
+        db, dc = targets
+        with jax.named_scope(f"ghost_fill/scatter/L{level}"):
+            return _flat3(f).at[db, :, dc].set(vals).reshape(f.shape)
+    with jax.named_scope(f"ghost_fill/slab/L{level}"):
+        pieces = _slab_pieces(f, slabs)
+    if vals is not None:
+        with jax.named_scope(f"ghost_fill/scatter/L{level}"):
+            pieces = _place_rows(pieces, vals, placement)
+    with jax.named_scope(f"ghost_fill/slab/L{level}"):
+        return _assemble(pieces)
+
+
+def _slab_pieces(f: jax.Array, slabs) -> dict:
+    """The 27 pieces of a ``(B, Q, X, Y, Z)`` level stack's tiles, keyed by
+    direction (``(0, 0, 0)`` is the interior), with the same-level ghost
+    slabs filled: for each direction the neighbours' interior boundary slabs
+    are gathered along the block axis (a clamped index per destination slot,
+    plus a per-slot select that keeps the old ghost values where the slab
+    has no same-level source)."""
+    pieces = {}
+    for k, d in enumerate((*SLAB_DIRECTIONS, (0, 0, 0))):
+        tgt, src = slab_slices(d, slabs.cells, slabs.ghost)
+        cur = f[(slice(None), slice(None), *tgt)]
+        ss = slabs.src_slot[k] if d != (0, 0, 0) else None
+        if ss is None or not (ss >= 0).any():
+            pieces[d] = cur
+            continue
+        vals = jnp.take(
+            f[(slice(None), slice(None), *src)],
+            jnp.asarray(np.maximum(ss, 0)),
+            axis=0,
+            mode="clip",
+        )
+        if not (ss >= 0).all():
+            has = jnp.asarray(ss >= 0)[:, None, None, None, None]
+            vals = jnp.where(has, vals, cur)
+        pieces[d] = vals
+    return pieces
+
+
+def _place_rows(pieces: dict, vals: jax.Array, placement: dict) -> dict:
+    """Write per-cell fill values into the ghost-slab pieces: for each
+    direction, the value rows of the slots that have targets there are
+    gathered into slab-shaped rows, placed along the block axis, and
+    selected cell by cell (:func:`~repro.lbm.halo.lower_slab_placement`)."""
+    q = vals.shape[1]
+    for d, (rows, pos, hit) in placement.items():
+        w = pieces[d].shape[2:]
+        rv = vals[jnp.asarray(rows)]  # (S, cells of the slab, Q)
+        rv = jnp.swapaxes(rv, 1, 2).reshape(rows.shape[0], q, *w)
+        placed = jnp.take(rv, jnp.asarray(pos), axis=0, mode="clip")
+        pieces[d] = jnp.where(jnp.asarray(hit.reshape(-1, 1, *w)), placed, pieces[d])
+    return pieces
+
+
+def _assemble(pieces: dict) -> jax.Array:
+    """Concatenate the 27 pieces back into the ``(B, Q, X, Y, Z)`` stack: one
+    pass that writes the whole stack once."""
+    r = (-1, 0, 1)
+    return jnp.concatenate([
+        jnp.concatenate([
+            jnp.concatenate([pieces[(dx, dy, dz)] for dz in r], axis=4)
+            for dy in r
+        ], axis=3)
+        for dx in r
+    ], axis=2)
 
 
 def _device_plan_ops(plan, level_index: dict[int, int]) -> list[tuple]:
@@ -314,6 +422,15 @@ def _concat_vals(bufs, gathers, level: int, extra=()) -> jax.Array:
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
+def _fill_targets(parts) -> tuple[np.ndarray, np.ndarray]:
+    """Concatenated (dst_slot, dst_cell) of a level's scatter parts; empty
+    when the slabs fill every ghost cell."""
+    if not parts:
+        return np.zeros(0, np.int32), np.zeros(0, np.int32)
+    return (np.concatenate([p[0] for p in parts]),
+            np.concatenate([p[1] for p in parts]))
+
+
 def apply_compiled_ghost_plan(plan, bufs: dict[int, jax.Array]) -> dict[int, jax.Array]:
     """Run one compiled single-field ghost exchange on per-level buffers.
 
@@ -343,6 +460,7 @@ def make_fused_superstep(
     unroll_limit: int = 32,
     donate: bool | None = None,
     halo_stepper_factory=None,
+    spec=None,
 ):
     """Compile one full coarse step — the whole ``2^lmax`` substep cycle with
     interleaved ghost exchange — into a single jitted device program.
@@ -385,13 +503,20 @@ def make_fused_superstep(
             for active levels with no fill, and for every level in the
             legacy schedule.
         masks: level -> device mask stack for that level's buffer.
-        halo_stepper_factory: optional ``(level, dst_slot, dst_cell) ->
-            step(f, vals)`` builder (see :func:`make_halo_stream_collide`).
+        halo_stepper_factory: optional ``(level, dst_slot, dst_cell,
+            slabs=None) -> step(f, vals)`` builder (see
+            :func:`make_halo_stream_collide`).
+        spec: the pdf field's block geometry (:class:`~repro.lbm.grid.
+            LBMBlockSpec`), required with ``halo_stepper_factory``: the
+            ghost slabs that same-level neighbours fill whole are copied
+            block by block and only the rest is written cell by cell
+            (:func:`~repro.lbm.halo.split_halo_fill`).
 
     Returns:
         A jitted ``superstep(pdfs: tuple) -> tuple`` advancing one coarse
         step; ``pdfs`` holds one (B, Q, X, Y, Z) buffer per level, ascending.
     """
+    assert halo_stepper_factory is None or spec is not None, "halo fills need spec"
     levels = tuple(sorted(levels))
     index = {l: i for i, l in enumerate(levels)}
     lmax = levels[-1]
@@ -412,23 +537,36 @@ def make_fused_superstep(
 
             return branch
 
-        fills = lower_halo_fill(plans[p])
+        fills = {
+            l: split_halo_fill(f, spec, masks_t[index[l]].shape[0])
+            for l, f in lower_halo_fill(plans[p]).items()
+        }
         assert set(fills) <= set(active), (sorted(fills), active)
-        gathers = {l: _lower_fill_gathers(f, index) for l, f in fills.items()}
+        gathers = {
+            l: _lower_fill_gathers(res, index)
+            for l, (_, res) in fills.items()
+            if res is not None
+        }
         hsteps = {
-            l: halo_stepper_factory(l, f.dst_slot, f.dst_cell)
-            for l, f in fills.items()
+            l: halo_stepper_factory(
+                l,
+                *_fill_targets([] if res is None else [(res.dst_slot, res.dst_cell)]),
+                slabs=slabs,
+            )
+            for l, (slabs, res) in fills.items()
         }
 
         def branch(pdfs):
             bufs = list(pdfs)
             # all fill values gather from the pre-step buffers (every source
-            # is an interior cell; every target a ghost cell — disjoint)
-            vals = {l: _concat_vals(bufs, gathers[l], l) for l in fills}
+            # is an interior cell; every target a ghost cell — disjoint); a
+            # level's slab copies read its own buffer, which no finer level's
+            # step writes, so they read pre-step values too
+            vals = {l: _concat_vals(bufs, g, l) for l, g in gathers.items()}
             for l in active:  # finest first
                 i = index[l]
                 if l in fills:
-                    bufs[i] = hsteps[l](bufs[i], vals[l])
+                    bufs[i] = hsteps[l](bufs[i], vals.get(l))
                 else:
                     bufs[i] = steppers[l](bufs[i], masks_t[i])
             return tuple(bufs)
@@ -657,6 +795,7 @@ def make_rank_absorb(
     *,
     donate: bool | None = None,
     halo_stepper_factory=None,
+    spec=None,
 ):
     """Compile one rank's receive+exchange+step side of a sharded substep.
 
@@ -672,7 +811,10 @@ def make_rank_absorb(
     message scatters targeting each level are merged into *one* fill per
     level and fused into that level's stencil program (halo-in-tile) — local
     fill values gather from the pre-step buffers, message values are sliced
-    straight from the payload operands. ``donate`` resolves through
+    straight from the payload operands; the local fills' same-level ghost
+    slabs are block-granular copies, as in :func:`make_fused_superstep`,
+    which needs ``spec`` (the pdf field's block geometry) with the factory.
+    ``donate`` resolves through
     :func:`~.lbm_collide.resolve_donate`; when on, the pdf tuple is donated
     so the substep runs ping-pong in place (payload operands are never
     donated — the fabric may still hold them).
@@ -680,6 +822,7 @@ def make_rank_absorb(
     Returns a jitted ``absorb(pdfs: tuple, msgs: tuple) -> tuple`` — one
     device program per (rank, activity pattern), no host contact.
     """
+    assert halo_stepper_factory is None or spec is not None, "halo fills need spec"
     order = tuple(sorted(active_levels, reverse=True))  # finest first, as the
     masks_t = {l: jnp.asarray(masks[l]) for l in order}  # host driver does
 
@@ -710,35 +853,36 @@ def make_rank_absorb(
             return tuple(bufs)
 
     else:
-        fills = (
-            lower_halo_fill(local_plan)
-            if local_plan is not None and local_plan.ops
-            else {}
-        )
+        fills = {
+            l: split_halo_fill(f, spec, masks[l].shape[0])
+            for l, f in (
+                lower_halo_fill(local_plan)
+                if local_plan is not None and local_plan.ops
+                else {}
+            ).items()
+        }
         # level -> merged fill: local segments first, then message slices, in
-        # (message, scatter-segment) order — dst rows and value parts aligned
+        # (message, scatter-segment) order — dst rows and value parts aligned;
+        # the local same-level slabs are copied before that fill
         per: dict[int, dict] = {
             l: {
-                "dst": [(f.dst_slot, f.dst_cell)],
-                "gath": _lower_fill_gathers(f, level_index),
+                "dst": [] if res is None else [(res.dst_slot, res.dst_cell)],
+                "gath": () if res is None else _lower_fill_gathers(res, level_index),
                 "msg": [],
+                "slabs": slabs,
             }
-            for l, f in fills.items()
+            for l, (slabs, res) in fills.items()
         }
         for mi, m in enumerate(messages):
             off = 0
             for dl, db, dc, n in m.scatter:
-                e = per.setdefault(dl, {"dst": [], "gath": (), "msg": []})
+                e = per.setdefault(dl, {"dst": [], "gath": (), "msg": [], "slabs": None})
                 e["dst"].append((db, dc))
                 e["msg"].append((mi, off, n))
                 off += n
         assert set(per) <= set(order), (sorted(per), order)
         hsteps = {
-            l: halo_stepper_factory(
-                l,
-                np.concatenate([d[0] for d in e["dst"]]),
-                np.concatenate([d[1] for d in e["dst"]]),
-            )
+            l: halo_stepper_factory(l, *_fill_targets(e["dst"]), slabs=e["slabs"])
             for l, e in per.items()
         }
 
@@ -752,11 +896,12 @@ def make_rank_absorb(
                     extra=[msgs[mi][off : off + n] for mi, off, n in e["msg"]],
                 )
                 for l, e in per.items()
+                if e["gath"] or e["msg"]
             }
             for l in order:
                 i = level_index[l]
-                if l in vals:
-                    bufs[i] = hsteps[l](bufs[i], vals[l])
+                if l in per:
+                    bufs[i] = hsteps[l](bufs[i], vals.get(l))
                 else:
                     bufs[i] = steppers[l](bufs[i], masks_t[l])
             return tuple(bufs)

@@ -131,15 +131,16 @@ class StepEngine:
         )
 
     def _halo_stepper_factory(self, masks_host: dict[int, np.ndarray]):
-        """``(level, dst_slot, dst_cell) -> step(f, vals)`` builder for the
-        halo-in-tile superstep paths; ``masks_host`` are host mask stacks
-        (copied — the factory's premask constants must not alias mutable
-        arena storage)."""
+        """``(level, dst_slot, dst_cell, slabs=None) -> step(f, vals)``
+        builder for the halo-in-tile superstep paths; ``masks_host`` are host
+        mask stacks (copied — the factory's premask constants must not alias
+        mutable arena storage)."""
 
-        def factory(level: int, dst_slot: np.ndarray, dst_cell: np.ndarray):
+        def factory(level: int, dst_slot: np.ndarray, dst_cell: np.ndarray,
+                    slabs=None):
             return make_halo_stream_collide(
                 dst_slot, dst_cell, level=level, mask=masks_host[level],
-                **self._stepper_kwargs(level),
+                slabs=slabs, **self._stepper_kwargs(level),
             )
 
         return factory
@@ -376,6 +377,7 @@ class FusedEngine(ArenaEngine):
                 masks={l: res.fetch(l, "mask") for l in levels},
                 donate=getattr(self.cfg, "donate_pdfs", None),
                 halo_stepper_factory=self._halo_stepper_factory(masks_host),
+                spec=self.sim.spec,
             )
         self._fused_key = key
         self._program_fresh = True
@@ -652,6 +654,7 @@ class FusedShardedEngine(ShardedEngine):
                         active_levels=rank_active,
                         donate=getattr(self.cfg, "donate_pdfs", None),
                         halo_stepper_factory=self._halo_stepper_factory(masks_host),
+                        spec=self.sim.spec,
                     )
         return progs
 

@@ -73,6 +73,11 @@ __all__ = [
     "HaloFillSegment",
     "LevelHaloFill",
     "lower_halo_fill",
+    "SLAB_DIRECTIONS",
+    "slab_slices",
+    "LevelSlabFill",
+    "split_halo_fill",
+    "lower_slab_placement",
     "CompiledRankMessage",
     "CompiledRankHaloPlan",
     "compile_rank_halo_plan",
@@ -586,6 +591,189 @@ def lower_halo_fill(plan: CompiledGhostPlan) -> dict[int, LevelHaloFill]:
         )
         for dl, ops in sorted(by_level.items())
     }
+
+
+# The 26 neighbour directions of a block, in lexicographic order.
+SLAB_DIRECTIONS: tuple[tuple[int, int, int], ...] = tuple(
+    (dx, dy, dz)
+    for dx in (-1, 0, 1)
+    for dy in (-1, 0, 1)
+    for dz in (-1, 0, 1)
+    if (dx, dy, dz) != (0, 0, 0)
+)
+
+
+def slab_slices(
+    d: tuple[int, int, int], cells: tuple[int, int, int], ghost: int
+) -> tuple[tuple[slice, ...], tuple[slice, ...]]:
+    """(target, source) per-axis slices of the ghost slab in direction ``d``
+    of a ghosted tile: the target is the destination's ghost slab, the
+    source the same-level neighbour's interior boundary slab it copies (the
+    neighbour's first ``ghost`` interior layers facing the destination).
+    ``d = (0, 0, 0)`` gives the interior as both."""
+    tgt, src = [], []
+    for di, n in zip(d, cells):
+        if di < 0:
+            tgt.append(slice(0, ghost))
+            src.append(slice(n, n + ghost))
+        elif di > 0:
+            tgt.append(slice(n + ghost, n + 2 * ghost))
+            src.append(slice(ghost, 2 * ghost))
+        else:
+            tgt.append(slice(ghost, n + ghost))
+            src.append(slice(ghost, n + ghost))
+    return tuple(tgt), tuple(src)
+
+
+def _slab_shape(d, cells, ghost) -> tuple[int, ...]:
+    return tuple(s.stop - s.start for s in slab_slices(d, cells, ghost)[0])
+
+
+def _ghost_slabs(dst_cell: np.ndarray, cells, ghost) -> tuple[np.ndarray, tuple]:
+    """Index into :data:`SLAB_DIRECTIONS` of the ghost slab each ghost cell
+    (flat ghosted id) lies in, and the cells' per-axis coordinates (int32)."""
+    dims = tuple(n + 2 * ghost for n in cells)
+    c = np.asarray(dst_cell, dtype=np.int32)
+    xyz = (c // (dims[1] * dims[2]), c // dims[2] % dims[1], c % dims[2])
+    code = np.zeros(c.shape, dtype=np.int32)
+    for a, n, w in zip(xyz, cells, (9, 3, 1)):
+        code += ((a >= ghost).astype(np.int32) + (a >= n + ghost)) * w
+    return code - (code > 13), xyz  # lexicographic, with the interior (13) left out
+
+
+@dataclass(frozen=True)
+class LevelSlabFill:
+    """Block-granular part of one level's halo fill: every ghost slab that a
+    same-level neighbour fills whole, as a plain copy of the neighbour's
+    interior boundary slab (:func:`slab_slices`).
+
+    ``src_slot[k, b]`` is the slot of the same-level neighbour of slot ``b``
+    in direction ``SLAB_DIRECTIONS[k]``, or ``-1`` where the slab is not
+    filled this way (domain boundary, cross-level neighbour, neighbour on
+    another rank, unused slot)."""
+
+    cells: tuple[int, int, int]
+    ghost: int
+    src_slot: np.ndarray  # (26, nslots) int32
+
+    @property
+    def num_cells(self) -> int:
+        return sum(
+            int((self.src_slot[k] >= 0).sum())
+            * int(np.prod(_slab_shape(d, self.cells, self.ghost)))
+            for k, d in enumerate(SLAB_DIRECTIONS)
+        )
+
+
+def split_halo_fill(
+    fill: LevelHaloFill, spec: LBMBlockSpec, nslots: int
+) -> tuple[LevelSlabFill | None, LevelHaloFill | None]:
+    """Split a merged level fill into its slab part and its residual part.
+
+    The rows of a same-level segment that fill one destination slot's ghost
+    slab in one direction completely, from one source slot, with the
+    interior boundary slab :func:`slab_slices` names, become that slab's
+    entry of the :class:`LevelSlabFill`. Every other row stays in the
+    residual :class:`LevelHaloFill`, in its original segment order. Slab
+    and residual cells partition the fill's cells. Either part is ``None``
+    when empty.
+
+    Args:
+        fill: one destination level's merged fill (:func:`lower_halo_fill`).
+        spec: the field's block geometry (cells per block and ghost width).
+        nslots: number of block slots in the destination level's stack.
+    """
+    cells, g = tuple(spec.cells), spec.ghost
+    dims = tuple(n + 2 * g for n in cells)
+    nd = len(SLAB_DIRECTIONS)
+    # a slab row's source cell is its target cell moved by -d * cells
+    shift = np.array([
+        ((-d[0] * cells[0]) * dims[1] - d[1] * cells[1]) * dims[2] - d[2] * cells[2]
+        for d in SLAB_DIRECTIONS
+    ], dtype=np.int32)
+    size = np.array([np.prod(_slab_shape(d, cells, g)) for d in SLAB_DIRECTIONS])
+    size = np.tile(size, nslots)  # per (slot, slab) key, slot-major
+    slab = np.full(nslots * nd, -1, dtype=np.int32)
+    is_slab = np.zeros(fill.num_cells, dtype=bool)
+    off = 0
+    for seg in fill.segments:
+        n = seg.src_slot.shape[0]
+        rows = slice(off, off + n)
+        off += n
+        if seg.kind != "same" or seg.src_level != fill.dst_level or not n:
+            continue
+        dc = fill.dst_cell[rows]
+        k, _ = _ghost_slabs(dc, cells, g)
+        # key the rows by (destination slot, slab); a key's rows are one
+        # slab copy if they cover the slab, from one source slot, cell for
+        # cell (the per-key tables are nslots x 26, the rows int32)
+        key = fill.dst_slot[rows] * np.int32(nd) + k
+        src = np.full(nslots * nd, -1, dtype=np.int32)
+        src[key] = seg.src_slot
+        bad = np.zeros(nslots * nd, dtype=bool)
+        bad[key[(seg.src_cell != dc + shift[k]) | (seg.src_slot != src[key])]] = True
+        whole = ~bad & (np.bincount(key, minlength=nslots * nd) == size)
+        slab[whole] = src[whole]
+        is_slab[rows] = whole[key]
+    if not is_slab.any():
+        return None, fill
+    slabs = LevelSlabFill(cells, g, np.ascontiguousarray(slab.reshape(nslots, nd).T))
+    keep = ~is_slab
+    if not keep.any():
+        return slabs, None
+    segments, off = [], 0
+    for seg in fill.segments:
+        sel = keep[off : off + seg.src_slot.shape[0]]
+        off += sel.size
+        if sel.any():
+            segments.append(HaloFillSegment(
+                src_level=seg.src_level, kind=seg.kind,
+                src_slot=seg.src_slot[sel], src_cell=seg.src_cell[sel],
+            ))
+    residual = LevelHaloFill(
+        field=fill.field,
+        dst_level=fill.dst_level,
+        dst_slot=fill.dst_slot[keep],
+        dst_cell=fill.dst_cell[keep],
+        segments=tuple(segments),
+    )
+    return slabs, residual
+
+
+def lower_slab_placement(
+    dst_slot: np.ndarray,
+    dst_cell: np.ndarray,
+    cells: tuple[int, int, int],
+    ghost: int,
+    nslots: int,
+) -> dict[tuple[int, int, int], tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """Lower per-cell fill targets (ghost cells, flat ghosted ids) to the
+    ghost slabs they lie in, so their values can be written slab by slab.
+
+    Returns direction -> ``(rows, pos, hit)`` for every direction whose slab
+    holds targets. With ``S`` the sorted slots that have targets there and
+    ``P`` the slab's cell count: ``rows`` ``(len(S), P)`` gives the target
+    row of each slab cell of each such slot (0 where the cell is no target);
+    ``pos`` ``(nslots,)`` gives each slot's position in ``S`` (0 for the
+    others); ``hit`` ``(nslots, P)`` marks the slab cells that are targets."""
+    k, xyz = _ghost_slabs(dst_cell, cells, ghost)
+    out = {}
+    for kk in np.unique(k):
+        d = SLAB_DIRECTIONS[kk]
+        sel = np.nonzero(k == kk)[0]
+        tgt, _ = slab_slices(d, cells, ghost)
+        w = _slab_shape(d, cells, ghost)
+        local = np.ravel_multi_index(tuple(c[sel] - t.start for c, t in zip(xyz, tgt)), w)
+        slots = np.asarray(dst_slot)[sel]
+        used = np.unique(slots)
+        pos = np.zeros(nslots, dtype=np.int32)
+        pos[used] = np.arange(used.size, dtype=np.int32)
+        rows = np.zeros((used.size, int(np.prod(w))), dtype=np.int32)
+        rows[pos[slots], local] = sel
+        hit = np.zeros((nslots, int(np.prod(w))), dtype=bool)
+        hit[slots, local] = True
+        out[d] = (rows, pos, hit)
+    return out
 
 
 # -- rank-sharded exchange (cross-rank ghosts as p2p messages) ------------------

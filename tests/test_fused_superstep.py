@@ -8,12 +8,18 @@
 * even-but-non-power-of-two cells per block are valid (the real halo
   alignment invariant), end to end through an AMR event;
 * a caller-owned ``plan_cache`` can never replay a plan built for an older
-  forest topology or storage binding.
+  forest topology or storage binding;
+* the slab-plus-residual halo fill (same-level ghost slabs as block-granular
+  copies, cross-level cells written cell by cell) fills the stack bit for
+  bit as the merged scatter does, and a built superstep records how many
+  cells each part fills.
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.core import (
     AMRPipeline,
     Comm,
@@ -22,10 +28,20 @@ from repro.core import (
     SFCBalancer,
     make_uniform_forest,
 )
+from repro.kernels.lbm_collide import ops
 from repro.kernels.lbm_collide.ops import apply_compiled_ghost_plan
 from repro.lbm import AMRLBM, LidDrivenCavityConfig
 from repro.lbm.grid import LBMBlockSpec, make_lbm_fields
-from repro.lbm.halo import build_ghost_plan, compile_ghost_plan, fill_ghost_layers
+from repro.lbm.halo import (
+    SLAB_DIRECTIONS,
+    build_ghost_plan,
+    compile_ghost_plan,
+    fill_ghost_layers,
+    lower_halo_fill,
+    lower_slab_placement,
+    slab_slices,
+    split_halo_fill,
+)
 
 
 def _seed_fields(forest, reg, rng=None):
@@ -41,17 +57,18 @@ def _seed_fields(forest, reg, rng=None):
         b.data["mask"] = np.zeros(reg.block_shape("mask"), np.int32)
 
 
-def _two_level_arena(cells=(4, 4, 4)):
-    """A 2-level forest (one root refined) with arena-backed random pdfs."""
+def _two_level_arena(cells=(4, 4, 4), root_grid=(2, 1, 1), refine=(0,)):
+    """A 2-level forest (the roots at positions ``refine`` of the sorted root
+    ids refined) with arena-backed random pdfs."""
     spec = LBMBlockSpec(cells=cells)
     reg = make_lbm_fields(spec)
-    geom = ForestGeometry(root_grid=(2, 1, 1), max_level=6)
+    geom = ForestGeometry(root_grid=root_grid, max_level=6)
     forest = make_uniform_forest(geom, 1, level=0)
     _seed_fields(forest, reg)  # migration serializes fields during the cycle
     pipe = AMRPipeline(balancer=SFCBalancer(), registry=reg)
-    root0 = min(b.bid for b in forest.all_blocks())
+    roots = sorted(b.bid for b in forest.all_blocks())
     forest, _ = pipe.run_cycle(
-        forest, Comm(1), lambda r, blocks: {root0: 1}
+        forest, Comm(1), lambda r, blocks: {roots[i]: 1 for i in refine}
     )
     assert forest.levels_in_use() == [0, 1]
     _seed_fields(forest, reg, rng=np.random.default_rng(7))
@@ -114,6 +131,108 @@ def test_compiled_plan_levels_filter_restricts_targets_not_sources():
     )
     assert all(op.dst_level == 1 for op in plan.ops)
     assert {op.src_level for op in plan.ops} == {0, 1}
+
+
+# -- slab-plus-residual halo fill -----------------------------------------------
+
+
+def _slab_cells(slabs) -> set[tuple[int, int]]:
+    """(slot, flat ghosted cell) of every ghost cell a slab part fills."""
+    dims = tuple(n + 2 * slabs.ghost for n in slabs.cells)
+    out = set()
+    for k, d in enumerate(SLAB_DIRECTIONS):
+        tgt, _ = slab_slices(d, slabs.cells, slabs.ghost)
+        cells = np.ravel_multi_index(
+            np.meshgrid(*(np.arange(t.start, t.stop) for t in tgt), indexing="ij"),
+            dims,
+        ).ravel()
+        for b in np.nonzero(slabs.src_slot[k] >= 0)[0]:
+            out.update((int(b), int(c)) for c in cells)
+    return out
+
+
+@pytest.mark.parametrize(
+    "pattern, level", [(0, 1), (1, 0), (1, 1)], ids=["p0-L1", "p1-L0", "p1-L1"]
+)
+def test_slab_fill_matches_merged_scatter_bitwise(pattern, level):
+    """On a two-level forest with domain boundaries and L0<->L1 interfaces,
+    in every activity pattern: the slab copies plus the residual's per-cell
+    placement leave the stack bitwise equal to the merged per-cell scatter, ghost
+    cells included; slab and residual cells partition the merged fill's
+    cells; the residual holds only cross-level segments."""
+    forest, reg, arena = _two_level_arena(root_grid=(3, 2, 2), refine=(0, 5))
+    levels = arena.levels()
+    slots = {l: arena.slots(l) for l in levels}
+    active = {l for l in levels if l >= max(levels) - pattern}
+    plan = compile_ghost_plan(forest, reg, slots, fields=("pdf",), levels=active)
+    fill = lower_halo_fill(plan)[level]
+    index = {l: i for i, l in enumerate(levels)}
+    bufs = [jnp.asarray(arena.buffer(l, "pdf")) for l in levels]
+    f = bufs[index[level]]
+
+    vals = ops._concat_vals(bufs, ops._lower_fill_gathers(fill, index), level)
+    merged = ops._halo_fill(
+        f, vals, level, (jnp.asarray(fill.dst_slot), jnp.asarray(fill.dst_cell)),
+        None, None,
+    )
+
+    slabs, residual = split_halo_fill(fill, LBMBlockSpec(cells=(4, 4, 4)), f.shape[0])
+    assert slabs is not None and residual is not None
+    rvals = ops._concat_vals(bufs, ops._lower_fill_gathers(residual, index), level)
+    placement = lower_slab_placement(
+        residual.dst_slot, residual.dst_cell, (4, 4, 4), 1, f.shape[0]
+    )
+    out = ops._halo_fill(f, rvals, level, None, slabs, placement)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(merged))
+
+    slab_cells = _slab_cells(slabs)
+    res_cells = set(zip(residual.dst_slot.tolist(), residual.dst_cell.tolist()))
+    all_cells = set(zip(fill.dst_slot.tolist(), fill.dst_cell.tolist()))
+    assert len(slab_cells) == slabs.num_cells
+    assert len(res_cells) == residual.num_cells
+    assert not slab_cells & res_cells
+    assert slab_cells | res_cells == all_cells
+    assert {seg.kind for seg in residual.segments} <= {"coarse", "fine"}
+    assert all(seg.src_level != level for seg in residual.segments)
+
+
+def test_fused_superstep_counts_slab_and_scatter_cells():
+    """Building a fused superstep records ``halo.slab_cells`` and
+    ``halo.scatter_cells`` per level; per level they add up to the cells of
+    the merged fills the superstep's activity patterns build."""
+    sim = AMRLBM(LidDrivenCavityConfig(
+        root_grid=(2, 2, 2), cells_per_block=(4, 4, 4), nranks=1, omega=1.5,
+        u_lid=(0.08, 0.0, 0.0), max_level=1, refine_upper=0.03,
+        refine_lower=0.004, kernel_backend="ref", stepping_mode="fused",
+    ))
+    sim.advance(2)
+    sim.adapt()
+    levels = sim.forest.levels_in_use()
+    assert levels == [0, 1]
+    tr = telemetry.get_tracer()
+    was = tr.enabled
+    telemetry.configure(enabled=True)
+    tr.reset()
+    try:
+        sim.advance(1)  # builds the superstep for the new topology
+        slab = tr.metrics.counter("halo.slab_cells").series()
+        scatter = tr.metrics.counter("halo.scatter_cells").series()
+    finally:
+        telemetry.configure(enabled=was)
+        tr.reset()
+    slots = {l: sim.arena.slots(l) for l in levels}
+    want = dict.fromkeys(levels, 0)
+    for p in range(len(levels)):
+        plan = compile_ghost_plan(
+            sim.forest, sim.fields, slots, fields=("pdf",),
+            levels={l for l in levels if l >= max(levels) - p},
+        )
+        for l, fill in lower_halo_fill(plan).items():
+            want[l] += fill.num_cells
+    for l in levels:
+        key = f"level={l}"
+        assert slab[key] > 0
+        assert slab[key] + scatter[key] == want[l]
 
 
 # -- satellite: ghost-width-0 slicing ------------------------------------------

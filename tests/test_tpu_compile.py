@@ -14,6 +14,7 @@ tests.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,9 +22,18 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import ForestGeometry, make_uniform_forest
 from repro.kernels.lbm_collide.lbm_collide import lbm_stream_collide_pallas
 from repro.kernels.lbm_collide.ops import make_halo_stream_collide
 from repro.kernels.lbm_collide.ref import CT_LID, CT_WALL
+from repro.lbm.grid import LBMBlockSpec
+from repro.lbm.halo import (
+    SLAB_DIRECTIONS,
+    LevelSlabFill,
+    compile_ghost_plan,
+    lower_halo_fill,
+    split_halo_fill,
+)
 from repro.lbm.lattice import D3Q19
 
 B, N = 64, 18  # blocks per call, tile edge (16 cells + 2 ghost)
@@ -105,3 +115,56 @@ def test_halo_in_tile_step_names_its_fill_and_kernel(one_chip):
     assert 'op_name="jit(step)/ghost_fill/scatter/L1/' in text
     kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert kernel and all(ln.lstrip().startswith("%lbm_collide") for ln in kernel)
+
+
+def test_slab_fill_step_compiles_natively_and_names_its_parts(one_chip):
+    """A fill with a slab part and per-cell values besides compiles for the
+    chip; the slab copies carry ``ghost_fill/slab/L<l>`` and the per-cell
+    values ``ghost_fill/scatter/L<l>`` in their ``op_name`` metadata."""
+    src = np.full((len(SLAB_DIRECTIONS), B), -1, np.int32)
+    src[SLAB_DIRECTIONS.index((1, 0, 0))] = (np.arange(B) + 1) % B
+    src[SLAB_DIRECTIONS.index((0, -1, 1)), ::2] = np.arange(1, B, 2)
+    slabs = LevelSlabFill((N - 2,) * 3, 1, src)
+    cells = np.arange(N * N, dtype=np.int32)  # the x = 0 face: per-cell values
+    step = make_halo_stream_collide(
+        np.repeat(np.arange(B, dtype=np.int32), cells.size), np.tile(cells, B),
+        level=1, mask=_mask(), slabs=slabs, backend="pallas", interpret=False, **KW
+    )
+    text = (
+        jax.jit(step)
+        .lower(
+            _sds((B, D3Q19.Q, N, N, N), jnp.float32, one_chip),
+            _sds((B * cells.size, D3Q19.Q), jnp.float32, one_chip),
+        )
+        .compile()
+        .as_text()
+    )
+    assert 'op_name="jit(step)/ghost_fill/slab/L1/' in text
+    assert 'op_name="jit(step)/ghost_fill/scatter/L1/' in text
+    assert "tpu_custom_call" in text
+
+
+def test_one_level_slab_fill_compiles_without_scatter(one_chip):
+    """On a one-level grid every ghost slab has a same-level source or none:
+    the whole fill is slab copies, and the compiled step holds no scatter
+    over the level stack."""
+    forest = make_uniform_forest(ForestGeometry(root_grid=(4, 4, 4), max_level=1), 1)
+    blocks = sorted(forest.all_blocks(), key=lambda b: b.bid)
+    assert len(blocks) == B
+    spec = LBMBlockSpec(cells=(N - 2,) * 3)
+    plan = compile_ghost_plan(forest, spec, {0: {b.bid: i for i, b in enumerate(blocks)}})
+    slabs, residual = split_halo_fill(lower_halo_fill(plan)[0], spec, B)
+    assert residual is None and slabs is not None
+    step = make_halo_stream_collide(
+        np.zeros(0, np.int32), np.zeros(0, np.int32), level=0, mask=_mask(),
+        slabs=slabs, backend="pallas", interpret=False, **KW
+    )
+    text = (
+        jax.jit(lambda f: step(f, None))
+        .lower(_sds((B, D3Q19.Q, N, N, N), jnp.float32, one_chip))
+        .compile()
+        .as_text()
+    )
+    assert "/ghost_fill/slab/L0/" in text
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\bscatter\(", text)
