@@ -1043,9 +1043,15 @@ def make_device_superstep(
     ``jax.lax.ppermute`` calls *inside* the program. Per-rank asymmetry — the
     gather/scatter index arrays of :func:`compile_rank_halo_plan` differ on
     every rank — is expressed as ``lax.switch`` on ``lax.axis_index``: each
-    branch closes over exactly one rank's index constants, so the arithmetic
-    (including the canonical fixed-order octet sum) is *identical* to the
-    host-fabric engines and the bitwise conformance contract carries over.
+    branch closes over exactly one rank's index constants, so the program
+    does the same arithmetic as the host-fabric engines (including the
+    canonical fixed-order octet sum); how it rounds is the backend's.
+
+    Each activity pattern ``p``'s parts carry device labels in the HLO's
+    ``op_name`` metadata: ``halo_emit/p<p>`` (the outbound gathers),
+    ``halo_ppermute/p<p>`` (the permute rounds, collective-permute
+    instructions on the device) and ``halo_absorb/p<p>`` (the inbound
+    scatters and the rank-local fill).
 
     Buffers are the equal-blocks-per-rank padded stacks: each per-level
     operand is ``(nranks, Bmax_l, ...)`` sharded on the leading axis, so every
@@ -1155,23 +1161,28 @@ def make_device_superstep(
 
         def branch(bufs, masks):
             if nranks == 1:
-                bufs = exchanges[0](bufs)
+                with jax.named_scope(f"halo_absorb/p{p}"):
+                    bufs = exchanges[0](bufs)
             else:
                 ridx = jax.lax.axis_index(axis)
                 if rounds:
-                    payloads = jax.lax.switch(ridx, emits, tuple(bufs))
-                    recvs = tuple(
-                        # repro: collective-ok(ppermute is a partial permutation — pure p2p halo routing, bytes attributed via DeviceComm.ppermute)
-                        jax.lax.ppermute(pl, axis, perm)
-                        for pl, perm in zip(payloads, perms)
-                    )
-                    bufs = jax.lax.switch(ridx, exchanges, tuple(bufs), recvs)
+                    with jax.named_scope(f"halo_emit/p{p}"):
+                        payloads = jax.lax.switch(ridx, emits, tuple(bufs))
+                    with jax.named_scope(f"halo_ppermute/p{p}"):
+                        recvs = tuple(
+                            # repro: collective-ok(ppermute is a partial permutation — pure p2p halo routing, bytes attributed via DeviceComm.ppermute)
+                            jax.lax.ppermute(pl, axis, perm)
+                            for pl, perm in zip(payloads, perms)
+                        )
+                    with jax.named_scope(f"halo_absorb/p{p}"):
+                        bufs = jax.lax.switch(ridx, exchanges, tuple(bufs), recvs)
                 else:
-                    bufs = jax.lax.switch(
-                        ridx,
-                        [lambda b, e=e: e(b) for e in exchanges],
-                        tuple(bufs),
-                    )
+                    with jax.named_scope(f"halo_absorb/p{p}"):
+                        bufs = jax.lax.switch(
+                            ridx,
+                            [lambda b, e=e: e(b) for e in exchanges],
+                            tuple(bufs),
+                        )
             bufs = list(bufs)
             for l in active:  # finest first, as the host driver orders
                 i = index[l]

@@ -286,6 +286,14 @@ class AMRLBM:
         device-resident mode (no-op in the host-resident modes)."""
         self.engine.materialize_host()
 
+    def pdfs_written(self) -> None:
+        """Announce a host write of ``Block.data["pdf"]`` views (made after
+        :meth:`materialize_host`): the engine drops its device copy of the
+        pdfs, so the next step uploads the written state. A no-op in the
+        host-resident modes; required after such a write in the
+        device-resident ones, whose device copy would otherwise win."""
+        self.engine.pdfs_written()
+
     # -- Lagrangian tracers -----------------------------------------------------
     def _step_particles(self) -> None:
         """Advect tracers through the end-of-step velocity field and route

@@ -21,6 +21,9 @@ Engine surface (see ARCHITECTURE.md for the mode matrix):
   invalidation by mechanism: caches are keyed to the storage version (every
   ``adopt`` bumps it), so no call site can replay a stale plan, mask, or
   compiled program.
+* :meth:`StepEngine.pdfs_written` — the pdf counterpart of
+  ``masks_refreshed``: host ``Block.data["pdf"]`` views were written after
+  ``materialize_host()``, so every device copy of the pdfs is stale.
 * :meth:`StepEngine.materialize_host` — flush device-newer state so every
   ``Block.data`` view is current (no-op for host-resident modes).
 * :meth:`StepEngine.particle_batches` — the advection batch source for the
@@ -198,6 +201,12 @@ class StepEngine:
         """Host-side mask write happened: device mask copies are stale."""
         self._mask_dev.clear()
 
+    def pdfs_written(self) -> None:
+        """Host ``Block.data["pdf"]`` views were written after
+        :meth:`materialize_host`: any device copy of the pdfs is stale and
+        the next step uploads the host state (no-op in the host-resident
+        modes, which step the host views themselves)."""
+
     def materialize_host(self) -> None:
         """Flush device-newer buffers so ``Block.data`` views are current
         (no-op in the host-resident modes)."""
@@ -339,6 +348,10 @@ class FusedEngine(ArenaEngine):
         self.arena.device().drop(name="mask")
         self._fused_fn = None
         self._fused_key = None
+
+    def pdfs_written(self) -> None:
+        # the residency asserts if this would discard an un-flushed step
+        self.arena.device().drop(name="pdf")
 
     def materialize_host(self) -> None:
         self.arena.device().flush()
@@ -539,6 +552,11 @@ class FusedShardedEngine(ShardedEngine):
                 arena.device().drop(name="mask")
         self._programs_cache = None
         self._programs_key = None
+
+    def pdfs_written(self) -> None:
+        for arena in self.arenas.per_rank:
+            if arena._residency is not None:
+                arena.device().drop(name="pdf")
 
     def materialize_host(self) -> None:
         for arena in self.arenas.per_rank:
@@ -787,7 +805,16 @@ class DeviceShardedEngine(ShardedEngine):
     never read by any halo plan (``verify_padded_plan``), unchanged by every
     step. The ``Comm`` fabric must be a :class:`~repro.core.comm.DeviceComm`
     (the driver wires this) so the in-program ppermute traffic lands in the
-    same Table-1 counters as every other mode.
+    same Table-1 counters as every other mode. The program does the same
+    arithmetic as the host-fabric engines (:func:`make_device_superstep`);
+    how it rounds is the backend's, so it agrees with them to float32
+    round-off, not bit for bit by contract.
+
+    Pad slots are stepped like real ones: :meth:`slot_layout` gives, per
+    level, the real blocks on each rank, the slots every step runs
+    (``Bmax × nranks``) and the pad's share of them; each program build
+    records them as telemetry (``device.real_blocks`` per level and rank,
+    ``device.stepped_slots`` per level, ``device.pad_share`` per level).
     """
 
     mode = "device_sharded"
@@ -833,6 +860,13 @@ class DeviceShardedEngine(ShardedEngine):
         super().masks_refreshed()
         self._dev_masks = None
 
+    def pdfs_written(self) -> None:
+        assert not self._host_stale, (
+            "materialize_host() before writing host pdfs: the device holds "
+            "newer steps the write would be mixed with"
+        )
+        self._dev_pdfs = None  # the next _ensure_device re-uploads the stacks
+
     def materialize_host(self) -> None:
         if not self._host_stale:
             return
@@ -856,6 +890,22 @@ class DeviceShardedEngine(ShardedEngine):
         # contract as fused_sharded's residency.
         self.materialize_host()
         super().exchange_ghosts(active)
+
+    def slot_layout(self) -> dict[int, dict]:
+        """Per level of the current forest: ``blocks`` (real blocks on each
+        rank), ``slots`` (the padded stack every step runs, ``Bmax ×
+        nranks``) and ``pad_share`` (the share of those slots that are pad)."""
+        nranks = self.cfg.nranks
+        rank_slots = {
+            r: {l: arena.slots(l) for l in arena.levels()}
+            for r, arena in enumerate(self.arenas.per_rank)
+        }
+        out = {}
+        for l, bmax in sorted(padded_block_counts(rank_slots, nranks).items()):
+            blocks = [len(rank_slots[r].get(l, ())) for r in range(nranks)]
+            out[l] = {"blocks": blocks, "slots": bmax * nranks,
+                      "pad_share": 1.0 - sum(blocks) / (bmax * nranks)}
+        return out
 
     # -- compiled programs -----------------------------------------------------
     def _programs(self) -> _DevicePrograms:
@@ -882,6 +932,12 @@ class DeviceShardedEngine(ShardedEngine):
             for r in range(nranks)
         }
         counts = padded_block_counts(rank_slots, nranks)
+        if _TR.enabled:
+            for l, lay in self.slot_layout().items():
+                for r, n in enumerate(lay["blocks"]):
+                    _TR.metrics.counter("device.real_blocks").inc(n, level=l, rank=r)
+                _TR.metrics.counter("device.stepped_slots").inc(lay["slots"], level=l)
+                _TR.metrics.gauge("device.pad_share").set(lay["pad_share"], level=l)
         pattern = [
             lmax if s == 0 else min((s & -s).bit_length() - 1, lmax)
             for s in range(nsub)

@@ -187,3 +187,38 @@ def test_padding_is_inert_under_the_kernel_and_preserves_mass(seed):
     assert np.float64(out_padded[:B].sum(dtype=np.float64)) == np.float64(
         out_real.sum(dtype=np.float64)
     )
+
+
+def test_device_sharded_build_records_its_slot_layout():
+    """Building the ``device_sharded`` program records the layout it pads
+    to (``DeviceShardedEngine.slot_layout``) as telemetry: real blocks per
+    level and rank, stepped slots and the pad share per level."""
+    from repro import telemetry
+    from repro.lbm import AMRLBM, LidDrivenCavityConfig
+
+    sim = AMRLBM(LidDrivenCavityConfig(
+        root_grid=(2, 2, 2), cells_per_block=(4, 4, 4), nranks=1, omega=1.5,
+        u_lid=(0.08, 0.0, 0.0), max_level=1, refine_upper=0.03,
+        refine_lower=0.004, kernel_backend="ref", stepping_mode="device_sharded",
+    ))
+    sim.advance(2)
+    sim.adapt()
+    tr = telemetry.get_tracer()
+    was = tr.enabled
+    telemetry.configure(enabled=True)
+    tr.reset()
+    try:
+        sim.advance(1)  # builds the program for the new topology
+        real = tr.metrics.counter("device.real_blocks").series()
+        stepped = tr.metrics.counter("device.stepped_slots").series()
+        pad = tr.metrics.gauge("device.pad_share").series()
+    finally:
+        telemetry.configure(enabled=was)
+        tr.reset()
+    layout = sim.engine.slot_layout()
+    assert sorted(layout) == [0, 1]
+    for level, lay in layout.items():
+        assert lay["blocks"] == [sum(b.level == level for b in sim.forest.all_blocks())]
+        assert real[f"level={level},rank=0"] == lay["blocks"][0]
+        assert stepped[f"level={level}"] == lay["slots"] == lay["blocks"][0]
+        assert pad[f"level={level}"] == lay["pad_share"] == 0.0

@@ -22,16 +22,30 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import ForestGeometry, make_uniform_forest
+from repro.core import (
+    AMRPipeline,
+    BlockDataRegistry,
+    Comm,
+    DiffusionBalancer,
+    ForestGeometry,
+    make_uniform_forest,
+)
 from repro.kernels.lbm_collide.lbm_collide import lbm_stream_collide_pallas
-from repro.kernels.lbm_collide.ops import make_halo_stream_collide
+from repro.kernels.lbm_collide.ops import (
+    make_device_superstep,
+    make_halo_stream_collide,
+    make_stream_collide,
+)
 from repro.kernels.lbm_collide.ref import CT_LID, CT_WALL
 from repro.lbm.grid import LBMBlockSpec
 from repro.lbm.halo import (
     SLAB_DIRECTIONS,
     LevelSlabFill,
     compile_ghost_plan,
+    compile_rank_halo_plan,
     lower_halo_fill,
+    padded_block_counts,
+    schedule_ppermute_rounds,
     split_halo_fill,
 )
 from repro.lbm.lattice import D3Q19
@@ -41,14 +55,18 @@ KW = dict(omega=1.5, lattice=D3Q19, u_wall=(0.08, 0.0, 0.0), collision="trt")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -168,3 +186,46 @@ def test_one_level_slab_fill_compiles_without_scatter(one_chip):
     assert "/ghost_fill/slab/L0/" in text
     assert "tpu_custom_call" in text
     assert not re.search(r"\bscatter\(", text)
+
+
+def test_device_superstep_compiles_for_four_chips_and_names_its_halo_parts(topo):
+    """The ``device_sharded`` superstep of a two-level forest split over four
+    ranks compiles for the four chips of a v5e 2x2: its halo permutes are
+    collective-permute instructions labelled ``halo_ppermute/p<p>``, and the
+    gathers and fills around them carry ``halo_emit/p<p>`` and
+    ``halo_absorb/p<p>``."""
+    nranks = 4
+    forest = make_uniform_forest(ForestGeometry(root_grid=(2, 2, 2), max_level=1), nranks)
+    first = min(b.bid for b in forest.all_blocks())
+    forest, _ = AMRPipeline(
+        balancer=DiffusionBalancer(mode="pushpull", flow_iterations=5),
+        registry=BlockDataRegistry.trivial(),
+    ).run_cycle(forest, Comm(nranks), lambda _r, blocks: {b: 1 for b in blocks if b == first})
+    assert sorted(forest.levels_in_use()) == [0, 1]
+    rank_slots: dict = {r: {} for r in range(nranks)}
+    for r in range(nranks):
+        for b in sorted(forest.local_blocks(r).values(), key=lambda b: b.bid):
+            level = rank_slots[r].setdefault(b.level, {})
+            level[b.bid] = len(level)
+    spec = LBMBlockSpec(cells=(N - 2,) * 3)
+    plans = {p: compile_rank_halo_plan(forest, spec, rank_slots, levels={l for l in (0, 1) if l >= 1 - p})
+             for p in (0, 1)}
+    schedules = {p: schedule_ppermute_rounds(plans[p].messages) for p in plans}
+    assert all(schedules.values())
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:nranks]), ("ranks",))
+    fn = make_device_superstep(
+        mesh=mesh, levels=(0, 1), plans=plans, schedules=schedules, donate=True,
+        steppers={l: make_stream_collide(backend="pallas", interpret=False, **KW) for l in (0, 1)},
+    )
+    sharded = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("ranks"))
+    counts = padded_block_counts(rank_slots, nranks)
+    text = fn.lower(
+        tuple(_sds((nranks, counts[l], D3Q19.Q, N, N, N), jnp.float32, sharded) for l in (0, 1)),
+        tuple(_sds((nranks, counts[l], N, N, N), jnp.int32, sharded) for l in (0, 1)),
+    ).compile().as_text()
+    permutes = [ln for ln in text.splitlines()
+                if re.search(r"= \S.* collective-permute(-start|-done)?\(", ln)]
+    assert permutes and all("/halo_ppermute/p" in ln for ln in permutes)
+    for part in ("halo_emit", "halo_absorb"):
+        assert f"/{part}/p0/" in text and f"/{part}/p1/" in text
+    assert "tpu_custom_call" in text
