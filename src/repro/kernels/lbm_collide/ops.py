@@ -26,6 +26,7 @@ import numpy as np
 
 from ...lbm.halo import (
     SLAB_DIRECTIONS,
+    LevelSlabFill,
     lower_halo_fill,
     lower_slab_placement,
     slab_slices,
@@ -260,30 +261,47 @@ def make_halo_stream_collide(
     return step
 
 
-def _count_fill_cells(level: int, slabs, n_scatter: int) -> None:
+def _count_fill_cells(level: int, slabs, n_scatter: int, **labels) -> None:
     """Record, per built fill, how many ghost cells the slab copies fill and
-    how many the per-cell scatter fills (``halo.slab_cells`` and
-    ``halo.scatter_cells``, labelled by level)."""
+    how many are written cell by cell (``halo.slab_cells`` and
+    ``halo.scatter_cells``, labelled by level and any further ``labels``)."""
     if not _TR.enabled:
         return
     n_slab = 0 if slabs is None else slabs.num_cells
-    _TR.metrics.counter("halo.slab_cells").inc(n_slab, level=level)
-    _TR.metrics.counter("halo.scatter_cells").inc(n_scatter, level=level)
+    _TR.metrics.counter("halo.slab_cells").inc(n_slab, level=level, **labels)
+    _TR.metrics.counter("halo.scatter_cells").inc(n_scatter, level=level, **labels)
 
 
 def _halo_fill(f: jax.Array, vals, level: int, targets, slabs, placement) -> jax.Array:
     """One level's ghost fill. Without a slab part: the merged per-cell
     scatter of ``vals`` to ``targets`` (``(dst_slot, dst_cell)`` device
-    arrays). With one: the same-level slabs copied block by block, ``vals``
-    (if any) written cell by cell into the slabs by ``placement``
-    (:func:`~repro.lbm.halo.lower_slab_placement`), and the stack assembled
-    in one pass, with no scatter."""
+    arrays). With one: :func:`_slab_fill` of the stack's own pieces."""
     if slabs is None:
         db, dc = targets
         with jax.named_scope(f"ghost_fill/scatter/L{level}"):
             return _flat3(f).at[db, :, dc].set(vals).reshape(f.shape)
+    return _slab_fill(_stack_cut(f, slabs.cells, slabs.ghost), vals, level, slabs, placement)
+
+
+def _stack_cut(f: jax.Array, cells, ghost):
+    """``cut(d, part)``: the piece of the ``(B, Q, X, Y, Z)`` stack ``f`` that
+    :func:`~repro.lbm.halo.slab_slices` names for direction ``d`` (``part`` 0:
+    the ghost slab, or the interior for ``(0, 0, 0)``; 1: the interior
+    boundary slab a neighbour in direction ``-d`` copies)."""
+
+    def cut(d, part):
+        return f[(slice(None), slice(None), *slab_slices(d, cells, ghost)[part])]
+
+    return cut
+
+
+def _slab_fill(cut, vals, level: int, slabs, placement) -> jax.Array:
+    """The same-level slabs copied block by block from the pieces ``cut``
+    gives, ``vals`` (if any) written cell by cell into the slabs by
+    ``placement`` (:func:`~repro.lbm.halo.lower_slab_placement`), and the
+    stack assembled in one pass, with no scatter."""
     with jax.named_scope(f"ghost_fill/slab/L{level}"):
-        pieces = _slab_pieces(f, slabs)
+        pieces = _slab_pieces(cut, slabs)
     if vals is not None:
         with jax.named_scope(f"ghost_fill/scatter/L{level}"):
             pieces = _place_rows(pieces, vals, placement)
@@ -291,23 +309,22 @@ def _halo_fill(f: jax.Array, vals, level: int, targets, slabs, placement) -> jax
         return _assemble(pieces)
 
 
-def _slab_pieces(f: jax.Array, slabs) -> dict:
-    """The 27 pieces of a ``(B, Q, X, Y, Z)`` level stack's tiles, keyed by
-    direction (``(0, 0, 0)`` is the interior), with the same-level ghost
-    slabs filled: for each direction the neighbours' interior boundary slabs
-    are gathered along the block axis (a clamped index per destination slot,
-    plus a per-slot select that keeps the old ghost values where the slab
-    has no same-level source)."""
+def _slab_pieces(cut, slabs) -> dict:
+    """The 27 pieces of a level stack's tiles (``cut``, see
+    :func:`_stack_cut`), keyed by direction (``(0, 0, 0)`` is the interior),
+    with the same-level ghost slabs filled: for each direction the
+    neighbours' interior boundary slabs are gathered along the block axis (a
+    clamped index per destination slot, plus a per-slot select that keeps the
+    old ghost values where the slab has no same-level source)."""
     pieces = {}
     for k, d in enumerate((*SLAB_DIRECTIONS, (0, 0, 0))):
-        tgt, src = slab_slices(d, slabs.cells, slabs.ghost)
-        cur = f[(slice(None), slice(None), *tgt)]
+        cur = cut(d, 0)
         ss = slabs.src_slot[k] if d != (0, 0, 0) else None
         if ss is None or not (ss >= 0).any():
             pieces[d] = cur
             continue
         vals = jnp.take(
-            f[(slice(None), slice(None), *src)],
+            cut(d, 1),
             jnp.asarray(np.maximum(ss, 0)),
             axis=0,
             mode="clip",
@@ -420,6 +437,43 @@ def _concat_vals(bufs, gathers, level: int, extra=()) -> jax.Array:
             parts.append(_gather_vals(bufs[si], kind, sb, sc))
     parts += list(extra)
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _merge_rank_fills(local_plan, messages, spec, nslots: dict[int, int],
+                      level_index: dict[int, int]) -> dict[int, dict]:
+    """One rank's ghost fill per destination level: the rank-local
+    same-level slabs (``slabs``, a :class:`~repro.lbm.halo.LevelSlabFill` or
+    ``None``) and the per-cell rest (``dst``: the local residual's targets
+    first, then each inbound message's scatter segments in (message,
+    segment) order), whose values are the local residual's gathers
+    (``gath``) followed by the ``(message, offset, rows)`` payload slices
+    (``msg``), row for row. ``nslots`` maps a level to its stack's slot
+    count."""
+    fills = {
+        l: split_halo_fill(f, spec, nslots[l])
+        for l, f in (
+            lower_halo_fill(local_plan)
+            if local_plan is not None and local_plan.ops
+            else {}
+        ).items()
+    }
+    per: dict[int, dict] = {
+        l: {
+            "dst": [] if res is None else [(res.dst_slot, res.dst_cell)],
+            "gath": () if res is None else _lower_fill_gathers(res, level_index),
+            "msg": [],
+            "slabs": slabs,
+        }
+        for l, (slabs, res) in fills.items()
+    }
+    for mi, m in enumerate(messages):
+        off = 0
+        for dl, db, dc, n in m.scatter:
+            e = per.setdefault(dl, {"dst": [], "gath": (), "msg": [], "slabs": None})
+            e["dst"].append((db, dc))
+            e["msg"].append((mi, off, n))
+            off += n
+    return per
 
 
 def _fill_targets(parts) -> tuple[np.ndarray, np.ndarray]:
@@ -853,33 +907,10 @@ def make_rank_absorb(
             return tuple(bufs)
 
     else:
-        fills = {
-            l: split_halo_fill(f, spec, masks[l].shape[0])
-            for l, f in (
-                lower_halo_fill(local_plan)
-                if local_plan is not None and local_plan.ops
-                else {}
-            ).items()
-        }
-        # level -> merged fill: local segments first, then message slices, in
-        # (message, scatter-segment) order — dst rows and value parts aligned;
-        # the local same-level slabs are copied before that fill
-        per: dict[int, dict] = {
-            l: {
-                "dst": [] if res is None else [(res.dst_slot, res.dst_cell)],
-                "gath": () if res is None else _lower_fill_gathers(res, level_index),
-                "msg": [],
-                "slabs": slabs,
-            }
-            for l, (slabs, res) in fills.items()
-        }
-        for mi, m in enumerate(messages):
-            off = 0
-            for dl, db, dc, n in m.scatter:
-                e = per.setdefault(dl, {"dst": [], "gath": (), "msg": [], "slabs": None})
-                e["dst"].append((db, dc))
-                e["msg"].append((mi, off, n))
-                off += n
+        per = _merge_rank_fills(
+            local_plan, messages, spec, {l: masks[l].shape[0] for l in masks},
+            level_index,
+        )
         assert set(per) <= set(order), (sorted(per), order)
         hsteps = {
             l: halo_stepper_factory(l, *_fill_targets(e["dst"]), slabs=e["slabs"])
@@ -1031,6 +1062,8 @@ def make_device_superstep(
     plans,
     schedules,
     steppers,
+    spec,
+    counts,
     unroll_limit: int = 32,
     donate: bool | None = None,
 ):
@@ -1050,15 +1083,32 @@ def make_device_superstep(
     Each activity pattern ``p``'s parts carry device labels in the HLO's
     ``op_name`` metadata: ``halo_emit/p<p>`` (the outbound gathers),
     ``halo_ppermute/p<p>`` (the permute rounds, collective-permute
-    instructions on the device) and ``halo_absorb/p<p>`` (the inbound
-    scatters and the rank-local fill).
+    instructions on the device) and ``halo_absorb/p<p>`` (the ghost fill).
+
+    The ghost fill takes the form :func:`make_fused_superstep`'s does, per
+    rank and level: the ghost slabs that a same-level neighbour on the same
+    rank fills whole are copied block by block from the stack itself
+    (``ghost_fill/slab/L<l>``); every other target, cross-level or inbound
+    from another rank, is written into the slab pieces by gather and select
+    (``ghost_fill/scatter/L<l>``, :func:`~repro.lbm.halo.
+    lower_slab_placement`), and the stack is assembled once. A level whose
+    fill has no slab part takes the same path with no slab sources. Which
+    cells take which form follows from the rank's plan alone; the program
+    holds no scatter. The slab-shaped pieces the fills read (every ghost
+    slab, the interior, and the source slabs some rank copies) are the same
+    on every rank, so they are cut once before the per-rank switch, and
+    each rank's branch copies and places from them. Each build records
+    ``halo.slab_cells`` and ``halo.scatter_cells`` per level and rank
+    (telemetry on).
 
     Buffers are the equal-blocks-per-rank padded stacks: each per-level
     operand is ``(nranks, Bmax_l, ...)`` sharded on the leading axis, so every
     shard sees ``(1, Bmax_l, ...)`` and rank-local slot ids address it
-    directly. Payloads for one :class:`~repro.lbm.halo.PpermuteRound` are
-    zero-padded to the round's ``num_cells`` so all participants ship one
-    shape; receivers scatter only the logical rows.
+    directly. Pad slots have no plan entries (:func:`~repro.lbm.halo.
+    verify_padded_plan`), so the fill leaves them as they are. Payloads for
+    one :class:`~repro.lbm.halo.PpermuteRound` are zero-padded to the
+    round's ``num_cells`` so all participants ship one shape; receivers
+    place only the logical rows.
 
     Args:
         mesh: 1-D ``jax.sharding.Mesh`` whose single axis enumerates ranks.
@@ -1071,6 +1121,10 @@ def make_device_superstep(
             ``plans[p].messages``.
         steppers: level -> ``step(f, mask) -> f`` (shared with every other
             engine — same kernel, same trace).
+        spec: the pdf field's block geometry (:class:`~repro.lbm.grid.
+            LBMBlockSpec`), which fixes the ghost slabs.
+        counts: level -> ``Bmax_l``, the padded per-rank slot count
+            (:func:`~repro.lbm.halo.padded_block_counts`).
 
     Returns:
         A jitted ``superstep(pdfs: tuple, masks: tuple) -> tuple`` advancing
@@ -1120,34 +1174,49 @@ def make_device_superstep(
 
         return emit
 
-    def make_exchange_branch(rank: int, rounds, plan):
-        inbound = []  # (round idx, lowered scatter segments), in round order
-        for k, rnd in enumerate(rounds):
-            for m in rnd.messages:
-                if m.dst_rank == rank:
-                    segs = tuple(
-                        (index[dl], jnp.asarray(db), jnp.asarray(dc), n)
-                        for dl, db, dc, n in m.scatter
-                    )
-                    inbound.append((k, segs))
-        local = plan.local.get(rank)
-        local_ops = _device_plan_ops(local, index) if local is not None else []
+    def rank_fill(rank: int, rounds, plan):
+        """``(ks, per, fills)`` of one rank: ``ks[mi]`` the round of its
+        inbound message ``mi`` (messages in round order), ``per`` its merged
+        fill (:func:`_merge_rank_fills`), and ``fills`` level -> the slab part
+        and the placement of the per-cell targets."""
+        inbound = [
+            (k, m) for k, rnd in enumerate(rounds) for m in rnd.messages
+            if m.dst_rank == rank
+        ]
+        per = _merge_rank_fills(
+            plan.local.get(rank), [m for _, m in inbound], spec, counts, index
+        )
+        fills = {}
+        for l, e in per.items():
+            dst_slot, dst_cell = _fill_targets(e["dst"])
+            slabs = e["slabs"] or LevelSlabFill(
+                tuple(spec.cells), spec.ghost,
+                np.full((len(SLAB_DIRECTIONS), counts[l]), -1, np.int32),
+            )
+            _count_fill_cells(l, slabs, dst_cell.size, rank=rank)
+            fills[l] = (slabs, lower_slab_placement(
+                dst_slot, dst_cell, slabs.cells, slabs.ghost, counts[l]
+            ))
+        return [k for k, _ in inbound], per, fills
 
-        def exchange(bufs, recvs=()):
+    def make_exchange_branch(ks, per, fills):
+        def exchange(bufs, cuts, recvs=()):
             bufs = list(bufs)
-            # inbound scatters write ghost cells, local gathers read interior
-            # cells — disjoint, so the order is immaterial (same argument as
-            # make_rank_absorb)
-            for k, segs in inbound:
-                msg = recvs[k]
-                off = 0
-                for li, db, dc, n in segs:
-                    d = bufs[li]
-                    bufs[li] = (
-                        _flat3(d).at[db, :, dc].set(msg[off : off + n]).reshape(d.shape)
-                    )
-                    off += n
-            bufs = _run_plan_ops(local_ops, bufs)
+            # every value gathers from the pre-fill buffers: sources are
+            # interior cells, targets ghost cells (as in make_rank_absorb)
+            vals = {
+                l: _concat_vals(
+                    bufs, e["gath"], l,
+                    extra=[recvs[ks[mi]][off : off + n] for mi, off, n in e["msg"]],
+                )
+                for l, e in per.items()
+                if e["gath"] or e["msg"]
+            }
+            for l, (slabs, placement) in fills.items():
+                i = index[l]
+                bufs[i] = _slab_fill(
+                    lambda d, part, c=cuts[i]: c[d, part], vals.get(l), l, slabs, placement
+                )
             return tuple(bufs)
 
         return exchange
@@ -1156,13 +1225,35 @@ def make_device_superstep(
         rounds = schedules[p]
         active = tuple(sorted((l for l in levels if l >= lmax - p), reverse=True))
         emits = [make_emit_branch(r, rounds) for r in range(nranks)]
-        exchanges = [make_exchange_branch(r, rounds, plans[p]) for r in range(nranks)]
+        ranks = [rank_fill(r, rounds, plans[p]) for r in range(nranks)]
+        exchanges = [make_exchange_branch(*rf) for rf in ranks]
         perms = [list(rnd.perm) for rnd in rounds]
+        # the pieces any rank's fill reads, per level: every ghost slab and
+        # the interior, and the source slabs of the directions some rank copies
+        parts: dict[int, set] = {}
+        for _, _, fills in ranks:
+            for l, (slabs, _) in fills.items():
+                keys = parts.setdefault(l, {(d, 0) for d in (*SLAB_DIRECTIONS, (0, 0, 0))})
+                keys.update(
+                    (d, 1) for k, d in enumerate(SLAB_DIRECTIONS)
+                    if (slabs.src_slot[k] >= 0).any()
+                )
+
+        def cut_pieces(bufs):
+            # the pieces are the same on every rank, so they are cut here, once,
+            # and not in each rank's switch branch: there a branch operand's
+            # fixed layout makes XLA relayout the whole stack for each cut
+            cuts = {}
+            for l, keys in sorted(parts.items()):
+                cut = _stack_cut(bufs[index[l]], tuple(spec.cells), spec.ghost)
+                with jax.named_scope(f"ghost_fill/slab/L{l}"):
+                    cuts[index[l]] = {key: cut(*key) for key in sorted(keys)}
+            return cuts
 
         def branch(bufs, masks):
             if nranks == 1:
                 with jax.named_scope(f"halo_absorb/p{p}"):
-                    bufs = exchanges[0](bufs)
+                    bufs = exchanges[0](bufs, cut_pieces(bufs))
             else:
                 ridx = jax.lax.axis_index(axis)
                 if rounds:
@@ -1175,13 +1266,13 @@ def make_device_superstep(
                             for pl, perm in zip(payloads, perms)
                         )
                     with jax.named_scope(f"halo_absorb/p{p}"):
-                        bufs = jax.lax.switch(ridx, exchanges, tuple(bufs), recvs)
+                        bufs = jax.lax.switch(
+                            ridx, exchanges, tuple(bufs), cut_pieces(bufs), recvs
+                        )
                 else:
                     with jax.named_scope(f"halo_absorb/p{p}"):
                         bufs = jax.lax.switch(
-                            ridx,
-                            [lambda b, e=e: e(b) for e in exchanges],
-                            tuple(bufs),
+                            ridx, exchanges, tuple(bufs), cut_pieces(bufs)
                         )
             bufs = list(bufs)
             for l in active:  # finest first, as the host driver orders
@@ -1215,12 +1306,12 @@ def make_device_superstep(
             bufs = jax.lax.fori_loop(0, nsub, body, bufs)
         return tuple(b[None] for b in bufs)
 
-    spec = jax.sharding.PartitionSpec(axis)
+    pspec = jax.sharding.PartitionSpec(axis)
     sm = jax.shard_map(
         mapped,
         mesh=mesh,
-        in_specs=(spec, spec),
-        out_specs=spec,
+        in_specs=(pspec, pspec),
+        out_specs=pspec,
         check_vma=False,  # lax.switch on axis_index is deliberately per-device
     )
     if resolve_donate(donate):

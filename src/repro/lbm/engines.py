@@ -808,7 +808,11 @@ class DeviceShardedEngine(ShardedEngine):
     same Table-1 counters as every other mode. The program does the same
     arithmetic as the host-fabric engines (:func:`make_device_superstep`);
     how it rounds is the backend's, so it agrees with them to float32
-    round-off, not bit for bit by contract.
+    round-off, not bit for bit by contract. Each rank fills its ghost layers
+    as the fused engine does: same-level slabs held on the rank are copied
+    block by block, and cross-level and inbound values are placed into the
+    slabs by gather and select, with no per-cell scatter (the build records
+    ``halo.slab_cells`` / ``halo.scatter_cells`` per level and rank).
 
     Pad slots are stepped like real ones: :meth:`slot_layout` gives, per
     level, the real blocks on each rank, the slots every step runs
@@ -972,6 +976,8 @@ class DeviceShardedEngine(ShardedEngine):
             plans=plans,
             schedules=schedules,
             steppers={l: self._fused_stepper(l) for l in levels},
+            spec=self.sim.spec,
+            counts=counts,
             donate=getattr(self.cfg, "donate_pdfs", None),
         )
         return _DevicePrograms(

@@ -2,9 +2,11 @@ import os
 import random
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from repro.core import Comm, ForestGeometry, make_uniform_forest
+from repro.lbm.halo import SLAB_DIRECTIONS, slab_slices
 
 
 @pytest.fixture
@@ -46,3 +48,18 @@ def four_cpu_devices_env() -> dict:
             [str(Path(__file__).resolve().parents[1] / "src"), os.environ.get("PYTHONPATH", "")]
         ),
     )
+
+
+def slab_cells(slabs) -> set[tuple[int, int]]:
+    """(slot, flat ghosted cell) of every ghost cell a slab part fills."""
+    dims = tuple(n + 2 * slabs.ghost for n in slabs.cells)
+    out = set()
+    for k, d in enumerate(SLAB_DIRECTIONS):
+        tgt, _ = slab_slices(d, slabs.cells, slabs.ghost)
+        cells = np.ravel_multi_index(
+            np.meshgrid(*(np.arange(t.start, t.stop) for t in tgt), indexing="ij"),
+            dims,
+        ).ravel()
+        for b in np.nonzero(slabs.src_slot[k] >= 0)[0]:
+            out.update((int(b), int(c)) for c in cells)
+    return out

@@ -189,10 +189,10 @@ def test_padding_is_inert_under_the_kernel_and_preserves_mass(seed):
     )
 
 
-def test_device_sharded_build_records_its_slot_layout():
-    """Building the ``device_sharded`` program records the layout it pads
-    to (``DeviceShardedEngine.slot_layout``) as telemetry: real blocks per
-    level and rank, stepped slots and the pad share per level."""
+def _device_sharded_build(*names: str):
+    """A one-rank ``device_sharded`` cavity after one criterion event (two
+    levels), and the series of the telemetry counters and gauges ``names``
+    that building its program for that topology records."""
     from repro import telemetry
     from repro.lbm import AMRLBM, LidDrivenCavityConfig
 
@@ -209,12 +209,21 @@ def test_device_sharded_build_records_its_slot_layout():
     tr.reset()
     try:
         sim.advance(1)  # builds the program for the new topology
-        real = tr.metrics.counter("device.real_blocks").series()
-        stepped = tr.metrics.counter("device.stepped_slots").series()
-        pad = tr.metrics.gauge("device.pad_share").series()
+        series = {n: tr.metrics.snapshot()[n]["series"] for n in names}
     finally:
         telemetry.configure(enabled=was)
         tr.reset()
+    return sim, series
+
+
+def test_device_sharded_build_records_its_slot_layout():
+    """Building the ``device_sharded`` program records the layout it pads
+    to (``DeviceShardedEngine.slot_layout``) as telemetry: real blocks per
+    level and rank, stepped slots and the pad share per level."""
+    sim, series = _device_sharded_build(
+        "device.real_blocks", "device.stepped_slots", "device.pad_share"
+    )
+    real, stepped, pad = series.values()
     layout = sim.engine.slot_layout()
     assert sorted(layout) == [0, 1]
     for level, lay in layout.items():
@@ -222,3 +231,33 @@ def test_device_sharded_build_records_its_slot_layout():
         assert real[f"level={level},rank=0"] == lay["blocks"][0]
         assert stepped[f"level={level}"] == lay["slots"] == lay["blocks"][0]
         assert pad[f"level={level}"] == lay["pad_share"] == 0.0
+
+
+def test_device_sharded_build_counts_slab_and_placed_cells_per_rank():
+    """Building the ``device_sharded`` program records ``halo.slab_cells``
+    and ``halo.scatter_cells`` per level and rank; per (level, rank) they
+    add up to the ghost cells the rank's local plans and inbound messages
+    target over the activity patterns, and the slab copies take some."""
+    sim, series = _device_sharded_build("halo.slab_cells", "halo.scatter_cells")
+    slab, scatter = series.values()
+    levels = sorted(sim.forest.levels_in_use())
+    arenas = sim.engine.arenas.per_rank
+    rank_slots = {r: {l: a.slots(l) for l in a.levels()} for r, a in enumerate(arenas)}
+    want: dict[str, int] = {}
+    for p in range(len(levels)):
+        plan = compile_rank_halo_plan(
+            sim.forest, sim.fields, rank_slots, fields=("pdf",),
+            levels={l for l in levels if l >= max(levels) - p},
+        )
+        for r, local in plan.local.items():
+            for op in local.ops:
+                key = f"level={op.dst_level},rank={r}"
+                want[key] = want.get(key, 0) + op.num_cells
+        for m in plan.messages:
+            for dl, _db, _dc, n in m.scatter:
+                key = f"level={dl},rank={m.dst_rank}"
+                want[key] = want.get(key, 0) + n
+    assert sorted(want) == [f"level={l},rank=0" for l in levels]
+    for key, n in want.items():
+        assert slab[key] > 0
+        assert slab[key] + scatter[key] == n, key

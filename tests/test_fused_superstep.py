@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import slab_cells
 from repro import telemetry
 from repro.core import (
     AMRPipeline,
@@ -33,13 +34,11 @@ from repro.kernels.lbm_collide.ops import apply_compiled_ghost_plan
 from repro.lbm import AMRLBM, LidDrivenCavityConfig
 from repro.lbm.grid import LBMBlockSpec, make_lbm_fields
 from repro.lbm.halo import (
-    SLAB_DIRECTIONS,
     build_ghost_plan,
     compile_ghost_plan,
     fill_ghost_layers,
     lower_halo_fill,
     lower_slab_placement,
-    slab_slices,
     split_halo_fill,
 )
 
@@ -136,21 +135,6 @@ def test_compiled_plan_levels_filter_restricts_targets_not_sources():
 # -- slab-plus-residual halo fill -----------------------------------------------
 
 
-def _slab_cells(slabs) -> set[tuple[int, int]]:
-    """(slot, flat ghosted cell) of every ghost cell a slab part fills."""
-    dims = tuple(n + 2 * slabs.ghost for n in slabs.cells)
-    out = set()
-    for k, d in enumerate(SLAB_DIRECTIONS):
-        tgt, _ = slab_slices(d, slabs.cells, slabs.ghost)
-        cells = np.ravel_multi_index(
-            np.meshgrid(*(np.arange(t.start, t.stop) for t in tgt), indexing="ij"),
-            dims,
-        ).ravel()
-        for b in np.nonzero(slabs.src_slot[k] >= 0)[0]:
-            out.update((int(b), int(c)) for c in cells)
-    return out
-
-
 @pytest.mark.parametrize(
     "pattern, level", [(0, 1), (1, 0), (1, 1)], ids=["p0-L1", "p1-L0", "p1-L1"]
 )
@@ -185,13 +169,13 @@ def test_slab_fill_matches_merged_scatter_bitwise(pattern, level):
     out = ops._halo_fill(f, rvals, level, None, slabs, placement)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(merged))
 
-    slab_cells = _slab_cells(slabs)
+    slab = slab_cells(slabs)
     res_cells = set(zip(residual.dst_slot.tolist(), residual.dst_cell.tolist()))
     all_cells = set(zip(fill.dst_slot.tolist(), fill.dst_cell.tolist()))
-    assert len(slab_cells) == slabs.num_cells
+    assert len(slab) == slabs.num_cells
     assert len(res_cells) == residual.num_cells
-    assert not slab_cells & res_cells
-    assert slab_cells | res_cells == all_cells
+    assert not slab & res_cells
+    assert slab | res_cells == all_cells
     assert {seg.kind for seg in residual.segments} <= {"coarse", "fine"}
     assert all(seg.src_level != level for seg in residual.segments)
 

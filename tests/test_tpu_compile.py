@@ -193,7 +193,9 @@ def test_device_superstep_compiles_for_four_chips_and_names_its_halo_parts(topo)
     ranks compiles for the four chips of a v5e 2x2: its halo permutes are
     collective-permute instructions labelled ``halo_ppermute/p<p>``, and the
     gathers and fills around them carry ``halo_emit/p<p>`` and
-    ``halo_absorb/p<p>``."""
+    ``halo_absorb/p<p>``. The fill copies rank-local same-level slabs
+    (``ghost_fill/slab/L0`` inside ``halo_absorb``) and places every other
+    value, so the program holds no scatter."""
     nranks = 4
     forest = make_uniform_forest(ForestGeometry(root_grid=(2, 2, 2), max_level=1), nranks)
     first = min(b.bid for b in forest.all_blocks())
@@ -213,12 +215,13 @@ def test_device_superstep_compiles_for_four_chips_and_names_its_halo_parts(topo)
     schedules = {p: schedule_ppermute_rounds(plans[p].messages) for p in plans}
     assert all(schedules.values())
     mesh = jax.sharding.Mesh(np.array(topo.devices[:nranks]), ("ranks",))
+    counts = padded_block_counts(rank_slots, nranks)
     fn = make_device_superstep(
         mesh=mesh, levels=(0, 1), plans=plans, schedules=schedules, donate=True,
         steppers={l: make_stream_collide(backend="pallas", interpret=False, **KW) for l in (0, 1)},
+        spec=spec, counts=counts,
     )
     sharded = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("ranks"))
-    counts = padded_block_counts(rank_slots, nranks)
     text = fn.lower(
         tuple(_sds((nranks, counts[l], D3Q19.Q, N, N, N), jnp.float32, sharded) for l in (0, 1)),
         tuple(_sds((nranks, counts[l], N, N, N), jnp.int32, sharded) for l in (0, 1)),
@@ -228,4 +231,6 @@ def test_device_superstep_compiles_for_four_chips_and_names_its_halo_parts(topo)
     assert permutes and all("/halo_ppermute/p" in ln for ln in permutes)
     for part in ("halo_emit", "halo_absorb"):
         assert f"/{part}/p0/" in text and f"/{part}/p1/" in text
+    assert re.search(r'op_name="[^"]*/halo_absorb/p1/[^"]*/ghost_fill/slab/L0/', text)
+    assert not re.search(r"\bscatter\(", text)
     assert "tpu_custom_call" in text
