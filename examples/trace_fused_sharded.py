@@ -7,10 +7,7 @@ counters — everything ``tools/trace_report.py`` renders.
 The 6x6x6 root grid matters: with 4 ranks, every rank then owns blocks with
 no cross-rank face, so the interior/boundary split of the fused_sharded
 substep actually engages (on a 4x4x4 grid every block of every rank is a
-boundary block and no ``interior`` span ever appears). ``overlap_split=True``
-forces the split on CPU too — a legitimate config override; the default
-resolves to False on CPU only to keep the *bitwise* conformance contract,
-which a trace run does not assert.
+boundary block and no ``interior`` span ever appears).
 
     PYTHONPATH=src python examples/trace_fused_sharded.py \
         [--out examples/traces/fused_sharded_4rank.trace.json]
@@ -38,7 +35,6 @@ def main() -> None:
         nranks=4,
         max_level=1,
         stepping_mode="fused_sharded",
-        overlap_split=True,  # see module docstring
     )
     sim = AMRLBM(cfg)
     sim.advance(args.steps // 2)

@@ -44,7 +44,6 @@ from .ref import CT_FLUID, CT_LID
 __all__ = [
     "lbm_stream_collide_pallas",
     "resolve_interpret",
-    "resolve_donate",
 ]
 
 # Scoped-VMEM budget for one whole-block tile. An 18^3 f32 D3Q19 tile needs
@@ -66,27 +65,6 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
     if interpret is None:
         return jax.default_backend() == "cpu"
     return bool(interpret)
-
-
-def resolve_donate(donate: bool | None = None) -> bool:
-    """Resolve the superstep buffer-donation flag once, at program-build time.
-
-    ``None`` (the default everywhere) means "donate exactly when the active
-    JAX backend is *not* CPU". On an accelerator the compiled substep
-    programs are memory-bound and donating the double-buffered pdf tuple
-    (``donate_argnums``) lets XLA ping-pong them in place, halving the HBM
-    footprint and eliminating the output allocation per substep. On XLA:CPU,
-    however, input/output aliasing feeds into LLVM's codegen (vectorization
-    and FMA contraction decisions change with the buffer assignment) and the
-    compiled stencil can differ from the undonated one by one ulp — measured
-    and deterministic, but enough to break the repo's bitwise conformance
-    contract between the fused modes and the host ``restack`` reference
-    (``--xla_cpu_enable_fast_math=false`` does not remove it). So the CPU
-    default keeps the value-identical path; an explicit bool overrides the
-    probe in either direction (the donation tests force ``True``)."""
-    if donate is None:
-        return jax.default_backend() != "cpu"
-    return bool(donate)
 
 
 def _roll(a, shift: tuple[int, int, int]):

@@ -34,11 +34,7 @@ from ...lbm.halo import (
 )
 from ...lbm.lattice import D3Q19, Lattice
 from ...telemetry import get_tracer
-from .lbm_collide import (
-    lbm_stream_collide_pallas,
-    resolve_donate,
-    resolve_interpret,
-)
+from .lbm_collide import lbm_stream_collide_pallas, resolve_interpret
 from .ref import (
     collision_coeffs,
     precompute_stream_masks,
@@ -62,7 +58,6 @@ __all__ = [
     "make_device_superstep",
     "boundary_slot_sets",
     "resolve_interpret",
-    "resolve_donate",
 ]
 
 
@@ -512,7 +507,6 @@ def make_fused_superstep(
     steppers,
     masks,
     unroll_limit: int = 32,
-    donate: bool | None = None,
     halo_stepper_factory=None,
     spec=None,
 ):
@@ -538,14 +532,10 @@ def make_fused_superstep(
     touches the host either way: the only transfers are the caller's initial
     upload and whatever diagnostics later flush back.
 
-    ``donate`` resolves through :func:`~.lbm_collide.resolve_donate`
-    (default: donate exactly when the backend is not CPU — XLA:CPU codegen
-    under aliasing perturbs the stencil by one ulp, which would break the
-    bitwise conformance contract). When donation is on, XLA aliases the
-    inputs into the outputs and the superstep ping-pongs the double-buffered
-    populations in place — callers must treat the passed-in arrays as
-    consumed (the engines re-``store`` the returned arrays into their
-    residency immediately).
+    The pdf tuple is donated: XLA aliases the inputs into the outputs and
+    the superstep ping-pongs the double-buffered populations in place —
+    callers must treat the passed-in arrays as consumed (the engines
+    re-``store`` the returned arrays into their residency immediately).
 
     Args:
         levels: refinement levels in use (the buffer tuple's order is the
@@ -646,9 +636,7 @@ def make_fused_superstep(
 
         return jax.lax.fori_loop(0, nsub, body, pdfs)
 
-    if resolve_donate(donate):
-        return jax.jit(superstep, donate_argnums=0)
-    return jax.jit(superstep)
+    return jax.jit(superstep, donate_argnums=0)
 
 
 def make_ensemble_superstep(
@@ -671,9 +659,9 @@ def make_ensemble_superstep(
     for all members. Because the coefficients are pre-rounded to the field
     dtype on the host (:func:`~repro.kernels.lbm_collide.ref.collision_coeffs`)
     and only ever combine as ``coefficient * array``, each member's slice of
-    the batched program is bitwise-identical to a solo fused run with the
-    same parameters on every interior cell (dead post-step ghost values may
-    round differently under the member ``vmap`` on XLA:CPU).
+    the batched program does a solo fused run's arithmetic with the same
+    parameters; how the backend rounds it may differ with the batch shape,
+    so the two agree to float32 round-off on every interior cell.
 
     Args:
         levels: refinement levels in use (ascending buffer-tuple order).
@@ -697,10 +685,8 @@ def make_ensemble_superstep(
     nsub = 1 << lmax
     masks_t = tuple(jnp.asarray(masks[l]) for l in levels)
     # host-precomputed streaming selectors, mirroring the solo fused path's
-    # merged-fill steppers (make_halo_stream_collide, backend="ref"): the
-    # batched program must trace the *same op structure* as a solo fused run
-    # or XLA:CPU's context-dependent rounding breaks the per-member bitwise
-    # contract (a structurally different batch drifts by one ulp)
+    # merged-fill steppers (make_halo_stream_collide, backend="ref"), so the
+    # batched program traces the same arithmetic as a solo fused run
     premasks = {
         # repro: host-ok(build-time d2h of the mask stack for selector precompute, once per program build)
         l: precompute_stream_masks(np.asarray(masks[l]), lattice) for l in levels
@@ -847,7 +833,6 @@ def make_rank_absorb(
     masks,
     active_levels,
     *,
-    donate: bool | None = None,
     halo_stepper_factory=None,
     spec=None,
 ):
@@ -868,10 +853,8 @@ def make_rank_absorb(
     straight from the payload operands; the local fills' same-level ghost
     slabs are block-granular copies, as in :func:`make_fused_superstep`,
     which needs ``spec`` (the pdf field's block geometry) with the factory.
-    ``donate`` resolves through
-    :func:`~.lbm_collide.resolve_donate`; when on, the pdf tuple is donated
-    so the substep runs ping-pong in place (payload operands are never
-    donated — the fabric may still hold them).
+    The pdf tuple is donated so the substep runs ping-pong in place (payload
+    operands are never donated — the fabric may still hold them).
 
     Returns a jitted ``absorb(pdfs: tuple, msgs: tuple) -> tuple`` — one
     device program per (rank, activity pattern), no host contact.
@@ -937,9 +920,7 @@ def make_rank_absorb(
                     bufs[i] = steppers[l](bufs[i], masks_t[l])
             return tuple(bufs)
 
-    if resolve_donate(donate):
-        return jax.jit(absorb, donate_argnums=0)
-    return jax.jit(absorb)
+    return jax.jit(absorb, donate_argnums=0)
 
 
 def make_rank_absorb_split(
@@ -949,8 +930,6 @@ def make_rank_absorb_split(
     steppers,
     masks,
     active_levels,
-    *,
-    donate: bool | None = None,
 ):
     """Split one rank's substep into an interior and a boundary program so
     cross-rank payload routing overlaps interior stepping.
@@ -966,12 +945,10 @@ def make_rank_absorb_split(
     program ``boundary(pdfs, msgs) -> pdfs`` scatters the inbound payloads
     and steps the boundary slots. The advance loop dispatches every rank's
     interior program *before* routing messages on the host, so the fabric
-    work hides behind interior compute; the two programs together are
-    bitwise equal to the unsplit absorb (per-block stepping is independent,
-    and every ghost fill lands before the slot that reads it steps).
-
-    Both programs donate their pdf tuple when ``donate`` (resolved through
-    :func:`~.lbm_collide.resolve_donate`) is on.
+    work hides behind interior compute; the two programs together do the
+    unsplit absorb's arithmetic (per-block stepping is independent, and
+    every ghost fill lands before the slot that reads it steps). Both
+    programs donate their pdf tuple.
     """
     order = tuple(sorted(active_levels, reverse=True))
     # repro: host-ok(build-time d2h of mask stacks for program lowering, once per arena version)
@@ -1047,12 +1024,10 @@ def make_rank_absorb_split(
             _step_subset(bufs, l, idx_bnd[l], "bnd")
         return tuple(bufs)
 
-    if resolve_donate(donate):
-        return (
-            jax.jit(interior, donate_argnums=0),
-            jax.jit(boundary, donate_argnums=0),
-        )
-    return jax.jit(interior), jax.jit(boundary)
+    return (
+        jax.jit(interior, donate_argnums=0),
+        jax.jit(boundary, donate_argnums=0),
+    )
 
 
 def make_device_superstep(
@@ -1065,7 +1040,6 @@ def make_device_superstep(
     spec,
     counts,
     unroll_limit: int = 32,
-    donate: bool | None = None,
 ):
     """Compile one coarse step as a single SPMD program over real XLA devices.
 
@@ -1314,9 +1288,7 @@ def make_device_superstep(
         out_specs=pspec,
         check_vma=False,  # lax.switch on axis_index is deliberately per-device
     )
-    if resolve_donate(donate):
-        return jax.jit(sm, donate_argnums=0)
-    return jax.jit(sm)
+    return jax.jit(sm, donate_argnums=0)
 
 
 def fused_stream_collide(

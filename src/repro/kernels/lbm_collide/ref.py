@@ -85,8 +85,8 @@ def collision_coeffs(
     computed in float64 exactly like the original closure path. The kernel
     then only ever combines them as ``coefficient * array``, so passing them
     as *traced* operands (the batched ensemble path, one value per member)
-    produces bitwise-identical results to baking them in as constants (the
-    single-run path): the rounding to ``dtype`` happens here, once, either way.
+    does the same arithmetic as baking them in as constants (the single-run
+    path): the rounding to ``dtype`` happens here, once, either way.
     """
     c = np.asarray(lattice.c)  # repro: host-ok(lattice constants are host numpy, folded into the program)
     w = np.asarray(lattice.w)  # repro: host-ok(lattice constants are host numpy, folded into the program)
@@ -117,8 +117,8 @@ def precompute_stream_masks(mask, lattice: Lattice = D3Q19) -> dict[str, np.ndar
     constant — this precomputes, on the host, exactly the booleans the kernel
     derives: ``fluid_src[q]`` / ``lid_src[q]`` are the rolled-mask comparisons
     for direction ``q``, ``fluid`` is the local-cell selector. Feeding them
-    through :func:`stream_collide_coeffs`'s ``premask`` argument produces
-    bitwise-identical results (identical booleans drive identical selects).
+    through :func:`stream_collide_coeffs`'s ``premask`` argument does the
+    same arithmetic (identical booleans drive identical selects).
 
     ``mask`` may be a single block ``(X, Y, Z)`` or a stack ``(B, X, Y, Z)``;
     rolls act on the trailing three axes and the ``q`` axis leads:

@@ -98,21 +98,6 @@ class LidDrivenCavityConfig:
     refine_lower: float = 0.015
     balancer: str = "diffusion-pushpull"  # | "diffusion-push" | "morton" | "hilbert"
     kernel_backend: str = "pallas"
-    # Pallas interpret override: None resolves once at program-build time to
-    # "interpret iff jax.default_backend() == 'cpu'" (see
-    # repro.kernels.lbm_collide.resolve_interpret); set a bool to force it
-    kernel_interpret: bool | None = None
-    # pdf buffer donation for the compiled superstep programs: None resolves
-    # at program-build time to "donate iff the backend is not CPU" (XLA:CPU
-    # codegen under aliasing drifts by one ulp, breaking the bitwise
-    # conformance contract; see repro.kernels.lbm_collide.resolve_donate)
-    donate_pdfs: bool | None = None
-    # interior/boundary split of the fused_sharded substep (overlaps host
-    # message routing with interior stepping): None resolves like donation —
-    # split iff the backend is not CPU, because XLA:CPU compiles the
-    # sub-stack stencil with context-dependent rounding (one ulp off the
-    # unsplit program, breaking the bitwise conformance contract)
-    overlap_split: bool | None = None
     # one StepEngine per mode; see README "Choosing a stepping mode"
     stepping_mode: str = "arena"  # | "fused" | "sharded" | "fused_sharded" | "device_sharded" | "restack"
     obstacle_fn: Callable[[np.ndarray], np.ndarray] | None = None  # (N,3)->bool

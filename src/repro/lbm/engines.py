@@ -128,9 +128,6 @@ class StepEngine:
             u_wall=cfg.u_lid,
             collision=cfg.collision,
             backend=cfg.kernel_backend,
-            # None resolves at program-build time: interpret iff the active
-            # backend is CPU (a real TPU/GPU lowers the kernel natively)
-            interpret=getattr(cfg, "kernel_interpret", None),
         )
 
     def _halo_stepper_factory(self, masks_host: dict[int, np.ndarray]):
@@ -388,7 +385,6 @@ class FusedEngine(ArenaEngine):
                 plans=plans,
                 steppers={l: self._fused_stepper(l) for l in levels},
                 masks={l: res.fetch(l, "mask") for l in levels},
-                donate=getattr(self.cfg, "donate_pdfs", None),
                 halo_stepper_factory=self._halo_stepper_factory(masks_host),
                 spec=self.sim.spec,
             )
@@ -640,14 +636,7 @@ class FusedShardedEngine(ShardedEngine):
                     masks_host[l].shape[0] - len(bnd.get(l, ()))
                     for l in rank_active
                 )
-                # the split is an accelerator optimization: XLA:CPU compiles
-                # the sub-stack stencil with context-dependent rounding (one
-                # ulp off the unsplit program), so the CPU default keeps the
-                # bitwise-conformant unsplit absorb (override: overlap_split)
-                split = getattr(self.cfg, "overlap_split", None)
-                if split is None:
-                    split = jax.default_backend() != "cpu"
-                if split and recvs and n_interior > 0:
+                if recvs and n_interior > 0:
                     # boundary blocks wait for inbound payloads; interior
                     # blocks don't — split so the host-side message routing
                     # overlaps the interior stepping dispatched before it
@@ -659,7 +648,6 @@ class FusedShardedEngine(ShardedEngine):
                             steppers=steppers,
                             masks=masks_dev,
                             active_levels=rank_active,
-                            donate=getattr(self.cfg, "donate_pdfs", None),
                         )
                     )
                 else:
@@ -670,7 +658,6 @@ class FusedShardedEngine(ShardedEngine):
                         steppers=steppers,
                         masks=masks_dev,
                         active_levels=rank_active,
-                        donate=getattr(self.cfg, "donate_pdfs", None),
                         halo_stepper_factory=self._halo_stepper_factory(masks_host),
                         spec=self.sim.spec,
                     )
@@ -978,7 +965,6 @@ class DeviceShardedEngine(ShardedEngine):
             steppers={l: self._fused_stepper(l) for l in levels},
             spec=self.sim.spec,
             counts=counts,
-            donate=getattr(self.cfg, "donate_pdfs", None),
         )
         return _DevicePrograms(
             levels=levels,

@@ -11,11 +11,12 @@ ownership reflects the new pool. Pass ``checkpoint_dir`` to route the
 snapshot through the on-disk files instead — the durable variant for
 shrinking after a real capacity loss.
 
-Bitwise contract: the codec round-trips every registered field — pdf
-*including ghost layers* and the mask — unchanged, and the sharded data
-planes are rank-count invariant (the same per-block kernel math and the same
-exchange values regardless of which rank owns a block), so a resized run
-continues bitwise-identically to a fixed-rank reference.
+Contract: the codec round-trips every registered field — pdf *including
+ghost layers* and the mask — bit for bit, and the sharded data planes do the
+same per-block kernel math and exchange the same values regardless of which
+rank owns a block, so a resized run continues a fixed-rank reference to
+float32 round-off (the backend may round a differently sized block stack
+differently).
 
 The control-plane half — deciding *when* and *how much* to resize — is the
 straggler/shrink planning ported from the seed ``train/elastic.py`` sketch:
@@ -87,7 +88,7 @@ def resize_ranks(
     Morton redistribution onto the new rank count → fresh comm fabric and
     stepping engine → optional forced balance cycle with the simulation's
     configured balancer. Works for every stepping mode (the snapshot goes
-    through materialized host views); physics continues bitwise-identically.
+    through materialized host views); physics continues to float32 round-off.
 
     With ``checkpoint_dir`` the snapshot round-trips through the on-disk
     checkpoint files (topology.json + per-rank payload pickles) instead of

@@ -11,13 +11,13 @@ per-member physics parameters (tau, lid velocity) enter as batched operands.
 One compile per (topology, activity-pattern) key serves every member — the
 classic inference-serving amortization.
 
-Bitwise contract: the batched program runs the identical op sequence as each
-member's solo fused run (coefficients are pre-rounded to the field dtype on
-the host by ``collision_coeffs`` either way), so member ``i``'s physical
-(interior-cell) state matches an independent single run with the same
-parameters bitwise. The ghost ring is excluded from the contract: post-step
-ghost values are dead (the next substep's fill overwrites them before any
-read) and XLA:CPU rounds them context-dependently under the member ``vmap``.
+Conformance: the batched program does the arithmetic of each member's solo
+fused run (coefficients are pre-rounded to the field dtype on the host by
+``collision_coeffs`` either way), so member ``i``'s physical (interior-cell)
+state matches an independent single run with the same parameters to float32
+round-off — the backend may round a batched build differently from a solo
+one. The ghost ring is excluded: post-step ghost values are dead (the next
+substep's fill overwrites them before any read).
 
 Divergence: members own their control planes (criterion, AMR pipeline), so
 refinement decisions may diverge. :meth:`Ensemble.adapt` materializes the
@@ -37,7 +37,7 @@ import numpy as np
 
 from ..core.pipeline import StageStats
 from ..telemetry import get_tracer
-from ..kernels.lbm_collide.ops import make_ensemble_superstep, resolve_donate
+from ..kernels.lbm_collide.ops import make_ensemble_superstep
 from ..kernels.lbm_collide.ref import collision_coeffs
 from ..lbm.halo import compile_ghost_plan
 from ..lbm.lattice import omega_for_level
@@ -95,21 +95,12 @@ def is_batchable(cfg: "LidDrivenCavityConfig") -> bool:
     (the batched program is built from the pure-jnp coefficient kernel, so
     solo references must run the same math), and no Lagrangian particles
     (tracer advection is per-member host work that would serialize the batch
-    anyway). A job that resolves to donated pdf buffers on XLA:CPU is also
-    excluded: the batched program never donates, and CPU codegen under
-    aliasing drifts by one ulp, so such a job's solo fused run would not
-    match its batched slice bitwise (on accelerators donation is
-    value-preserving and stays batchable).
+    anyway).
     """
-    donation_drifts = (
-        resolve_donate(getattr(cfg, "donate_pdfs", None))
-        and jax.default_backend() == "cpu"
-    )
     return (
         cfg.stepping_mode in ("arena", "fused")
         and cfg.kernel_backend == "ref"
         and cfg.particles is None
-        and not donation_drifts
     )
 
 

@@ -32,7 +32,7 @@ def safe_rebind(pdfs, cfg):
 def sanctioned_read(pdfs, cfg):
     fn = make_fused_superstep(**cfg)
     fn(pdfs)
-    # repro: donation-ok(fixture: cpu backend resolves donate off, buffer survives)
+    # repro: donation-ok(fixture: exercises the allowlist annotation)
     return pdfs  # NEG-ANNOTATED: allowlisted
 
 

@@ -16,16 +16,17 @@ conformance suite walks:
   activity pattern's compiled plan on any partition;
 * **schedule** — the ppermute rounds are partial permutations covering
   every message exactly once, for any partition;
-* **dead writes** — stepping a padded stack leaves real slots bitwise equal
-  to stepping the unpadded stack and padded slots (all-WALL mask, weight
-  PDFs) bitwise unchanged: the pad value is an exact fixed point of the
-  stream+collide kernel, so total mass over real slots is preserved exactly.
+* **dead writes** — stepping a padded stack leaves real slots equal to
+  stepping the unpadded stack (to the float32 conformance tolerance: the
+  compiler may round a larger stack differently) and padded slots (all-WALL
+  mask, weight PDFs) bitwise unchanged: the pad value is an exact fixed
+  point of the stream+collide kernel, so padding adds no mass to real slots.
 """
 
 import numpy as np
 import pytest
 
-from conftest import make_random_marks
+from conftest import assert_f32_match, assert_mass_match, make_random_marks
 from repro.core import (
     AMRPipeline,
     BlockDataRegistry,
@@ -139,10 +140,10 @@ def test_verify_padded_plan_detects_an_out_of_range_slot():
 
 @pytest.mark.parametrize("seed", SEEDS)
 def test_padding_is_inert_under_the_kernel_and_preserves_mass(seed):
-    """Stepping the padded stack == stepping the real stack, bitwise, and the
-    padded slots are an exact fixed point (weight PDFs under all-WALL masks):
-    masked-slot writes are provably dead and total mass over real slots is
-    exactly the unpadded mass."""
+    """Stepping the padded stack == stepping the real stack to float32
+    round-off, and the padded slots are an exact fixed point (weight PDFs
+    under all-WALL masks): masked-slot writes are provably dead and total
+    mass over real slots is the unpadded mass."""
     rng = np.random.default_rng(seed)
     Q = SPEC.lattice.Q
     shape = tuple(c + 2 * SPEC.ghost for c in SPEC.cells)
@@ -169,7 +170,8 @@ def test_padding_is_inert_under_the_kernel_and_preserves_mass(seed):
     )
 
     # padding preserves total mass: exactly the real mass plus the known
-    # inert pad contribution (weights sum to 1 per cell)
+    # inert pad contribution (weights sum to 1 per cell); concatenation only
+    # copies, so the real slots are bitwise the input
     assert np.asarray(padded_pdf[:B]).tobytes() == pdf.tobytes()
 
     step = make_stream_collide(
@@ -178,14 +180,17 @@ def test_padding_is_inert_under_the_kernel_and_preserves_mass(seed):
     out_real = np.asarray(step(pdf, mask))
     out_padded = np.asarray(step(padded_pdf, padded_mask))
 
-    # real slots: bitwise identical to the unpadded step (vmapped kernel is
-    # per-block, so padding cannot perturb real physics)
-    assert out_padded[:B].tobytes() == out_real.tobytes()
-    # padded slots: bitwise unchanged — the write is provably dead
+    # real slots: the vmapped kernel is per-block, so padding cannot perturb
+    # real physics beyond how the compiler rounds a larger stack
+    assert_f32_match(out_padded[:B], out_real, what="real slots of the padded step")
+    # padded slots: bitwise unchanged — exact by construction, the kernel
+    # writes f_out * 0 + f * 1 on non-fluid cells, which is f in IEEE arithmetic
     assert out_padded[B:].tobytes() == pad_pdf.tobytes()
-    # and therefore mass over real slots is exactly preserved by padding
-    assert np.float64(out_padded[:B].sum(dtype=np.float64)) == np.float64(
-        out_real.sum(dtype=np.float64)
+    # and therefore mass over real slots is preserved by padding
+    assert_mass_match(
+        float(out_padded[:B].sum(dtype=np.float64)),
+        float(out_real.sum(dtype=np.float64)),
+        what="real slots of the padded step",
     )
 
 

@@ -162,11 +162,13 @@ def device_fills() -> dict:
         fn = make_device_superstep(
             mesh=mesh, levels=LEVELS, plans={0: plan, 1: plan}, schedules={0: sched, 1: sched},
             steppers={l: (lambda f, m: f) for l in LEVELS}, spec=SPEC, counts=counts,
-            donate=False,
         )
-        got = fn(tuple(jax.device_put(s, sharding) for s in stacks), masks)
+        # the program consumes its pdf operands; the host fill reads `stacks`
+        got = fn(tuple(jax.device_put(s.copy(), sharding) for s in stacks), masks)
         want = host_fill(plan, stacks)
         out[p] = {
+            # exact by construction: identity steppers, so the program only
+            # moves values (and sums octets in the host's fixed order)
             "bitwise": {l: np.asarray(got[INDEX[l]]).tobytes() == want[INDEX[l]].tobytes()
                         for l in LEVELS},
             "filled": {l: bool((want[INDEX[l]] != stacks[INDEX[l]]).any()) for l in LEVELS},

@@ -3,16 +3,17 @@
 Pins the invariants that make ``stepping_mode="sharded"`` a faithful
 distributed execution of the single-rank reference, ``stepping_mode="fused"``
 a faithful *device-resident* one, and ``stepping_mode="fused_sharded"`` both
-at once — same fields to 1e-10 across an AMR event, mass conserved, zero
+at once — same fields to the float32 conformance tolerance
+(``conftest.F32_ATOL``) across an AMR event, mass conserved, zero
 host<->device transfers per substep in steady state (asserted on the
 residency layers' counters), and cross-rank traffic that stays p2p-only with
 byte-exact parity between the host patches and the device-built messages:
 
 * **conformance** — the full AMR+LBM cycle at 1/4/13 simulated ranks
   reproduces the single-rank restack reference macroscopic fields
-  (density/velocity) within 1e-10 after 8 coarse steps spanning at least one
-  AMR event (in practice the match is bitwise: identical kernels, identical
-  exchange arithmetic, only ownership differs);
+  (density/velocity) to float32 round-off after 8 coarse steps spanning at
+  least one AMR event (identical kernels, identical exchange arithmetic, only
+  ownership and so the stack shapes the compiler rounds for differ);
 * **communication shape** — ghost exchange puts only point-to-point traffic
   on the fabric, every communicating rank pair is a process-graph neighbor
   pair, and stepping triggers no collectives at all;
@@ -23,8 +24,8 @@ byte-exact parity between the host patches and the device-built messages:
 import numpy as np
 import pytest
 
-from repro.lbm import AMRLBM, LidDrivenCavityConfig
-from repro.lbm.criteria import macroscopic
+from conftest import assert_f32_match, assert_mass_match, macroscopic64
+from repro.lbm import AMRLBM, LidDrivenCavityConfig, engines
 from repro.lbm.halo import RankHaloPlan
 
 COARSE_STEPS = 8
@@ -63,7 +64,9 @@ def test_sharded_matches_single_rank_reference(reference, nranks):
     assert len(sim.forest.levels_in_use()) > 1
     # ownership-independent topology + fields: same leaves, same physics
     _assert_macroscopic_match(sim, reference)
-    assert abs(sim.total_mass() - reference.total_mass()) < 1e-6
+    assert_mass_match(
+        sim.total_mass(), reference.total_mass(), what=sim.cfg.stepping_mode
+    )
 
 
 def test_sharded_stepping_uses_only_p2p_next_neighbor_traffic():
@@ -102,31 +105,28 @@ def _assert_macroscopic_match(sim: AMRLBM, reference: AMRLBM) -> None:
     assert set(ref_blocks) == set(got_blocks)
     for bid, rb in ref_blocks.items():
         gb = got_blocks[bid]
-        rho_r, u_r = macroscopic(rb.data["pdf"], sim.spec.lattice)
-        rho_g, u_g = macroscopic(gb.data["pdf"], sim.spec.lattice)
+        rho_r, u_r = macroscopic64(rb.data["pdf"], sim.spec.lattice)
+        rho_g, u_g = macroscopic64(gb.data["pdf"], sim.spec.lattice)
         g = sim.spec.ghost
         sl = (slice(g, -g),) * 3
-        np.testing.assert_allclose(
-            np.asarray(rho_g)[sl], np.asarray(rho_r)[sl], rtol=0, atol=1e-10
-        )
-        np.testing.assert_allclose(
-            np.asarray(u_g)[(Ellipsis, *sl)],
-            np.asarray(u_r)[(Ellipsis, *sl)],
-            rtol=0,
-            atol=1e-10,
+        assert_f32_match(rho_g[sl], rho_r[sl], what=f"rho of block {bid:#x}")
+        assert_f32_match(
+            u_g[(Ellipsis, *sl)], u_r[(Ellipsis, *sl)], what=f"u of block {bid:#x}"
         )
 
 
 def test_fused_matches_restack_reference_across_amr(reference):
     """The device-resident fused superstep is a faithful execution of the
-    substep cycle: identical macroscopic fields (1e-10; in practice bitwise
-    — the compiled exchange mirrors the host resampling arithmetic exactly)
-    after 8 coarse steps spanning an AMR event, and mass is conserved."""
+    substep cycle: the same macroscopic fields to float32 round-off (the
+    compiled exchange mirrors the host resampling arithmetic exactly) after 8
+    coarse steps spanning an AMR event, and mass is conserved."""
     sim = _run("fused", 1)
     assert sim.amr_cycles >= 1, "the run must span at least one AMR event"
     assert len(sim.forest.levels_in_use()) > 1
     _assert_macroscopic_match(sim, reference)
-    assert abs(sim.total_mass() - reference.total_mass()) < 1e-6
+    assert_mass_match(
+        sim.total_mass(), reference.total_mass(), what=sim.cfg.stepping_mode
+    )
     # mass conservation against the initial condition (equilibrium at rho=1:
     # one unit per fluid root-cell volume)
     fresh = AMRLBM(LidDrivenCavityConfig(nranks=1, stepping_mode="fused", **BASE))
@@ -177,8 +177,9 @@ def test_fused_checkpoint_after_materialize_matches_reference(tmp_path):
     g = sim.spec.ghost
     sl = (Ellipsis,) + (slice(g, -g),) * 3
     for bid, rb in ref_blocks.items():
-        np.testing.assert_allclose(
-            got_blocks[bid].data["pdf"][sl], rb.data["pdf"][sl], rtol=0, atol=1e-10
+        assert_f32_match(
+            got_blocks[bid].data["pdf"][sl], rb.data["pdf"][sl],
+            what=f"restored pdf of block {bid:#x}",
         )
 
 
@@ -209,14 +210,16 @@ def test_fused_transfers_only_on_amr_events():
 def test_fused_sharded_matches_single_rank_reference(reference, nranks):
     """The per-rank device-resident data plane is a faithful distributed
     execution: fused_sharded at 1/4/13 ranks reproduces the single-rank
-    restack reference (1e-10; in practice bitwise — identical kernels,
-    identical exchange arithmetic on device, only ownership differs) after
-    8 coarse steps spanning an AMR event, and mass is conserved."""
+    restack reference to float32 round-off (identical kernels, identical
+    exchange arithmetic on device, only ownership differs) after 8 coarse
+    steps spanning an AMR event, and mass is conserved."""
     sim = _run("fused_sharded", nranks)
     assert sim.amr_cycles >= 1, "the run must span at least one AMR event"
     assert len(sim.forest.levels_in_use()) > 1
     _assert_macroscopic_match(sim, reference)
-    assert abs(sim.total_mass() - reference.total_mass()) < 1e-6
+    assert_mass_match(
+        sim.total_mass(), reference.total_mass(), what=sim.cfg.stepping_mode
+    )
 
 
 def test_fused_sharded_steady_state_performs_zero_host_transfers():
@@ -324,12 +327,12 @@ def test_rank_arenas_partition_data_by_owner_across_amr():
 
 # -- pallas-backend legs -------------------------------------------------------
 # The pallas kernel computes moments with unrolled per-direction arithmetic
-# (the ref kernel uses einsum contractions), so pallas runs are NOT bitwise
-# against ref runs — the cross-backend tolerance lives in
-# tests/test_kernels_lbm.py. Within the backend the conformance contract is
-# the same as for ref: every fused mode matches a pallas *restack* reference
-# at 1e-10 (in practice bitwise) across an AMR event. Shorter schedule than
-# the ref legs — interpret mode is slow.
+# (the ref kernel uses einsum contractions), so pallas runs agree with ref
+# runs only to the cross-backend tolerance in tests/test_kernels_lbm.py.
+# Within the backend the conformance contract is the same as for ref: every
+# fused mode matches a pallas *restack* reference to the float32 conformance
+# tolerance across an AMR event. Shorter schedule than the ref legs —
+# interpret mode is slow.
 
 PALLAS_STEPS = 4
 PALLAS_INTERVAL = 2  # AMR cycles after steps 2 and 4: spans >= 1 event
@@ -362,7 +365,9 @@ def test_pallas_fused_modes_match_pallas_restack_reference(
     assert sim.amr_cycles >= 1, "the run must span at least one AMR event"
     assert len(sim.forest.levels_in_use()) > 1
     _assert_macroscopic_match(sim, pallas_reference)
-    assert abs(sim.total_mass() - pallas_reference.total_mass()) < 1e-6
+    assert_mass_match(
+        sim.total_mass(), pallas_reference.total_mass(), what=mode
+    )
 
 
 def test_pallas_fused_steady_state_performs_zero_host_transfers():
@@ -378,59 +383,103 @@ def test_pallas_fused_steady_state_performs_zero_host_transfers():
     assert (res.h2d_transfers, res.d2h_transfers) == before
 
 
-def test_pallas_donated_superstep_consumes_buffers_and_survives_amr():
-    """Explicit ``donate_pdfs=True``: the superstep ping-pongs the pdf
-    buffers in place (inputs are deleted after each call), AMR events rebuild
-    the programs without ever touching a stale donated buffer, and the
-    physics stays within float32 round-off of the undonated twin (donation
-    perturbs XLA:CPU codegen by ~1 ulp per step, which is why it is not the
-    CPU default)."""
+def test_pallas_donated_superstep_consumes_buffers_and_survives_amr(
+    pallas_reference,
+):
+    """The superstep donates its pdf buffers and ping-pongs them in place
+    (inputs are deleted after each call); AMR events rebuild the programs
+    without ever touching a stale donated buffer, and the physics matches
+    the pallas restack reference on the same schedule."""
     cfg = {**BASE, "kernel_backend": "pallas"}
-    don = AMRLBM(
-        LidDrivenCavityConfig(
-            nranks=1, stepping_mode="fused", donate_pdfs=True, **cfg
-        )
-    )
-    ref = AMRLBM(
-        LidDrivenCavityConfig(
-            nranks=1, stepping_mode="fused", donate_pdfs=False, **cfg
-        )
-    )
+    don = AMRLBM(LidDrivenCavityConfig(nranks=1, stepping_mode="fused", **cfg))
 
     don.advance(1)
     lvl = min(don.forest.levels_in_use())
     held = don.arena.device().fetch(lvl, "pdf")
     don.advance(1)
     assert held.is_deleted(), "donated superstep must consume its inputs"
-    ref.advance(2)
 
     # cross an AMR event: programs rebuild, residency re-uploads — a stale
     # donated buffer anywhere in the engine would raise on next use
     don.adapt()
-    ref.adapt()
     assert len(don.forest.levels_in_use()) > 1
     don.advance(PALLAS_INTERVAL)
-    ref.advance(PALLAS_INTERVAL)
-    don.adapt()
-    ref.adapt()
+    don.adapt()  # the schedule of _run_pallas: PALLAS_STEPS, AMR every interval
 
-    # undonated twin: same program minus aliasing; only codegen round-off
     assert don.amr_cycles >= 1
-    ref_blocks = {b.bid: b for b in ref.forest.all_blocks()}
-    got_blocks = {b.bid: b for b in don.forest.all_blocks()}
-    assert set(ref_blocks) == set(got_blocks)
-    don.materialize_host()
-    ref.materialize_host()
-    g = don.spec.ghost
+    _assert_state_match(don, pallas_reference)
+    assert_mass_match(
+        don.total_mass(), pallas_reference.total_mass(), what="donated fused"
+    )
+
+
+def _assert_state_match(sim: AMRLBM, reference: AMRLBM) -> None:
+    """Interior pdfs, density and velocity of every block to the float32
+    conformance tolerance."""
+    sim.materialize_host()
+    reference.materialize_host()
+    _assert_macroscopic_match(sim, reference)
+    ref_blocks = {b.bid: b for b in reference.forest.all_blocks()}
+    g = sim.spec.ghost
     sl = (Ellipsis,) + (slice(g, -g),) * 3
-    for bid, rb in ref_blocks.items():
-        np.testing.assert_allclose(
-            got_blocks[bid].data["pdf"][sl],
-            rb.data["pdf"][sl],
-            rtol=0,
-            atol=1e-6,
+    for b in sim.forest.all_blocks():
+        assert_f32_match(
+            b.data["pdf"][sl], ref_blocks[b.bid].data["pdf"][sl],
+            what=f"pdf of block {b.bid:#x}",
         )
-    assert abs(don.total_mass() - ref.total_mass()) < 1e-6
+
+
+# Planted faults: each is a real bug's signature, planted in a pallas fused
+# run on the reference's schedule. Every one must fail the conformance
+# tolerance, so the tolerance is tight enough to see it.
+FAULTS = ("cell", "behind", "omega")
+
+
+def _faulted_fused_run(fault: str, monkeypatch) -> AMRLBM:
+    if fault == "omega":  # one level relaxes at a rate off by 1e-4
+        real = engines.omega_for_level
+        monkeypatch.setattr(
+            engines, "omega_for_level",
+            lambda omega, level: real(omega, level) + (1e-4 if level == 0 else 0.0),
+        )
+    cfg = {**BASE, "kernel_backend": "pallas"}
+    sim = AMRLBM(LidDrivenCavityConfig(nranks=1, stepping_mode="fused", **cfg))
+    for i in range(PALLAS_STEPS):
+        if fault == "cell" and i == PALLAS_INTERVAL:
+            # one interior cell's populations off by 1e-5, after the first
+            # AMR event, so the fault crosses steps and the second event
+            sim.materialize_host()
+            blk = min(sim.forest.all_blocks(), key=lambda b: b.bid)
+            blk.data["pdf"][:, 4, 4, 4] += np.float32(1e-5)
+            sim.pdfs_written()
+        if fault == "behind" and i == PALLAS_STEPS - 1:
+            # one block's pdfs left one coarse step behind
+            sim.materialize_host()
+            blk = max(sim.forest.all_blocks(), key=lambda b: (b.level, b.bid))
+            before = np.array(blk.data["pdf"])
+            sim.advance(1)
+            sim.materialize_host()
+            blk.data["pdf"][...] = before
+            sim.pdfs_written()
+        else:
+            sim.advance(1)
+        if (i + 1) % PALLAS_INTERVAL == 0:
+            sim.adapt()
+    return sim
+
+
+@pytest.mark.parametrize("fault", FAULTS)
+def test_planted_faults_fail_the_conformance_tolerance(
+    pallas_reference, fault, monkeypatch
+):
+    """A fused run across an AMR event with one planted fault no longer
+    matches the pallas restack reference under the float32 conformance
+    tolerance (``conftest.F32_ATOL``), while the same run without a fault
+    does (``test_pallas_fused_modes_match_pallas_restack_reference``)."""
+    sim = _faulted_fused_run(fault, monkeypatch)
+    assert sim.amr_cycles >= 1, "the run must span at least one AMR event"
+    with pytest.raises(AssertionError, match="float32 conformance tolerance"):
+        _assert_state_match(sim, pallas_reference)
 
 
 # -- device-matrix legs --------------------------------------------------------
@@ -456,15 +505,17 @@ def _require_devices(n: int) -> None:
 def test_device_sharded_matches_single_rank_reference(reference, nranks):
     """The real multi-device data plane is a faithful distributed execution:
     device_sharded at 1/2/4 devices reproduces the single-rank restack
-    reference (1e-10; in practice bitwise — the per-rank switch branches run
-    the identical exchange arithmetic, only placement differs) after 8 coarse
+    reference to float32 round-off (the per-rank switch branches run the
+    identical exchange arithmetic, only placement differs) after 8 coarse
     steps spanning an AMR event, and mass is conserved."""
     _require_devices(nranks)
     sim = _run("device_sharded", nranks)
     assert sim.amr_cycles >= 1, "the run must span at least one AMR event"
     assert len(sim.forest.levels_in_use()) > 1
     _assert_macroscopic_match(sim, reference)
-    assert abs(sim.total_mass() - reference.total_mass()) < 1e-6
+    assert_mass_match(
+        sim.total_mass(), reference.total_mass(), what=sim.cfg.stepping_mode
+    )
 
 
 def test_device_sharded_traffic_is_p2p_with_host_plan_byte_parity():

@@ -226,7 +226,7 @@ def test_buddy_snapshot_survives_in_place_arena_stepping():
     got = {b.bid: b.data["pdf"] for b in restored.all_blocks()}
     assert got.keys() == at_snapshot.keys()
     for bid, pdf in got.items():
-        np.testing.assert_array_equal(pdf, at_snapshot[bid])
+        np.testing.assert_array_equal(pdf, at_snapshot[bid])  # restore copies bytes
         # restored state owns its memory: stepping it must not touch the
         # snapshot (so a second restore from the same snapshot stays valid)
         for snap in mgr.snapshots:
@@ -242,7 +242,7 @@ def test_copy_policy_passes_payload_opaque():
     item = reg.items["meta"]
     d = np.arange(2 * 6 * 6 * 6, dtype=np.float32).reshape(reg.block_shape("meta"))
     child = item.deserialize_split(item.serialize_split(d, None, 5), None)
-    np.testing.assert_array_equal(child, d)
+    np.testing.assert_array_equal(child, d)  # "copy" policy: exact by construction
     assert child is not d  # children must not alias the parent
     merged = item.deserialize_merge({o: item.serialize_merge(d, None) for o in range(8)}, None)
     np.testing.assert_array_equal(merged, d)
@@ -258,7 +258,7 @@ def test_ghost_zero_field_splits_and_merges():
     rng = np.random.default_rng(2)
     d = rng.random(reg.block_shape("t"))
     assert d.shape == CELLS  # no ghost padding
-    np.testing.assert_array_equal(reg.interior("t", d), d)
+    np.testing.assert_array_equal(reg.interior("t", d), d)  # a slice: exact
     children = {
         o: item.deserialize_split(item.serialize_split(d, None, o), None) for o in range(8)
     }

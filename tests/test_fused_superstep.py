@@ -92,6 +92,8 @@ def test_compiled_plan_matches_host_exchange_bitwise():
     out = apply_compiled_ghost_plan(plan, {l: b for l, b in bufs.items()})
 
     fill_ghost_layers(forest, reg, fields=("pdf",))  # host reference, in place
+    # exact by construction: gathers, a fixed-order octet sum and a
+    # power-of-two scale are the same correctly rounded operations on both sides
     for l in arena.levels():
         np.testing.assert_array_equal(
             np.asarray(out[l]), arena.buffer(l, "pdf"), err_msg=f"level {l}"
@@ -167,6 +169,7 @@ def test_slab_fill_matches_merged_scatter_bitwise(pattern, level):
         residual.dst_slot, residual.dst_cell, (4, 4, 4), 1, f.shape[0]
     )
     out = ops._halo_fill(f, rvals, level, None, slabs, placement)
+    # exact by construction: both forms only move values, they compute nothing
     np.testing.assert_array_equal(np.asarray(out), np.asarray(merged))
 
     slab = slab_cells(slabs)
@@ -349,5 +352,5 @@ def test_plan_cache_rebuilds_on_topology_change():
     # and the rebuilt plan actually produced cross-level ghost fills
     ref = {b.bid: np.array(b.data["pdf"]) for b in forest.all_blocks()}
     fill_ghost_layers(forest, reg, fields=("pdf",))  # cacheless reference
-    for b in forest.all_blocks():
+    for b in forest.all_blocks():  # one plan's operations replayed: exact
         np.testing.assert_array_equal(b.data["pdf"], ref[b.bid])

@@ -89,34 +89,25 @@ def test_wall_cells_frozen_and_lid_injects_momentum():
 
 
 @pytest.mark.parametrize(
-    "backend,want_interpret,want_donate",
-    [("cpu", True, False), ("gpu", False, True), ("tpu", False, True)],
+    "backend,want_interpret",
+    [("cpu", True), ("gpu", False), ("tpu", False)],
 )
-def test_build_time_flag_resolution_per_backend(
-    monkeypatch, backend, want_interpret, want_donate
-):
-    """Pin the build-time resolution of the kernel-dispatch flags.
+def test_build_time_flag_resolution_per_backend(monkeypatch, backend, want_interpret):
+    """Pin the build-time resolution of the kernel's ``interpret`` flag.
 
     ``interpret=None`` must resolve to "interpret iff CPU" (the old hardwired
-    ``interpret=True`` silently ran the Pallas interpreter on accelerators),
-    and ``donate=None`` to "donate iff not CPU" (XLA:CPU codegen under
-    aliasing drifts by one ulp, breaking bitwise conformance). Explicit bools
-    always win over the backend probe.
+    ``interpret=True`` silently ran the Pallas interpreter on accelerators).
+    Explicit bools always win over the backend probe.
     """
     import jax
 
-    from repro.kernels.lbm_collide.lbm_collide import (
-        resolve_donate,
-        resolve_interpret,
-    )
+    from repro.kernels.lbm_collide.lbm_collide import resolve_interpret
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert resolve_interpret(None) is want_interpret
-    assert resolve_donate(None) is want_donate
     # explicit overrides ignore the backend entirely
     for flag in (True, False):
         assert resolve_interpret(flag) is flag
-        assert resolve_donate(flag) is flag
 
 
 def test_flag_resolution_happens_at_build_time(monkeypatch):

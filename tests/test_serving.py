@@ -4,12 +4,14 @@ resize, and the submit/poll/stream job driver.
 Conformance discipline matches tests/test_distributed_conformance.py: the
 batched ensemble path must reproduce independent single-run references
 (fused device superstep) across an AMR event, and an elastic resize mid-run
-must continue bitwise-identically to a fixed-rank reference.
+must continue a fixed-rank reference, both to the float32 conformance
+tolerance (``conftest.F32_ATOL``).
 """
 
 import numpy as np
 import pytest
 
+from conftest import assert_f32_match
 from repro.core.checkpoint import load_checkpoint
 from repro.lbm.driver import AMRLBM, LidDrivenCavityConfig
 from repro.serving import JobSpec, SimulationService, is_batchable, resize_ranks
@@ -41,12 +43,12 @@ def _cfg(**over) -> LidDrivenCavityConfig:
     return LidDrivenCavityConfig(**{**BASE, **over})
 
 
-def _assert_same_fields(sim: AMRLBM, ref: AMRLBM, *, atol: float) -> None:
+def _assert_same_fields(sim: AMRLBM, ref: AMRLBM, *, bitwise: bool = False) -> None:
     # pdf comparison covers the interior (physical) cells, matching the
     # distributed-conformance discipline: the ghost ring is scratch state,
-    # overwritten by the next substep's fill before anything reads it, and
-    # XLA:CPU rounds dead ghost-cell stencil outputs context-dependently
-    # across differently-batched (vmap-ed) builds of the same program
+    # overwritten by the next substep's fill before anything reads it.
+    # ``bitwise`` is for two runs of one program on one input (determinism);
+    # every other pair is held to the float32 conformance tolerance
     sim.materialize_host()
     ref.materialize_host()
     key = lambda f: sorted((b.bid, b.level) for b in f.all_blocks())
@@ -55,18 +57,19 @@ def _assert_same_fields(sim: AMRLBM, ref: AMRLBM, *, atol: float) -> None:
     core = (slice(None), slice(1, -1), slice(1, -1), slice(1, -1))
     for b in sim.forest.all_blocks():
         rb = ref_blocks[b.bid]
+        # masks are set from the topology, never stepped: exact
         np.testing.assert_array_equal(b.data["mask"], rb.data["mask"])
         p, q = b.data["pdf"][core], rb.data["pdf"][core]
-        if atol == 0.0:
+        if bitwise:
             np.testing.assert_array_equal(p, q)
         else:
-            np.testing.assert_allclose(p, q, rtol=0.0, atol=atol)
+            assert_f32_match(p, q, what=f"pdf of block {b.bid:#x}")
 
 
 def test_ensemble_matches_independent_references_across_amr():
     """>=4 batched members with different tau / lid velocities match solo
-    fused references at 1e-10 across an AMR event, with at most one compile
-    per (topology, activity-pattern) key for the whole batch."""
+    fused references to float32 round-off across an AMR event, with at most
+    one compile per (topology, activity-pattern) key for the whole batch."""
     refs = []
     for over in MEMBERS:
         ref = AMRLBM(_cfg(stepping_mode="fused", **over))
@@ -90,7 +93,7 @@ def test_ensemble_matches_independent_references_across_amr():
     for jid, ref in zip(ids, refs):
         job = svc.jobs[jid]
         assert job.status == "done" and job.step == COARSE_STEPS
-        _assert_same_fields(job.sim, ref, atol=1e-10)
+        _assert_same_fields(job.sim, ref)
         amr_happened = amr_happened or job.sim.amr_cycles > 0
     assert amr_happened, "the run must cross an AMR event"
 
@@ -116,9 +119,10 @@ def test_ensemble_matches_independent_references_across_amr():
 
 
 @pytest.mark.parametrize("nranks", [(4, 2), (2, 6)])
-def test_elastic_resize_preserves_physics_bitwise(nranks):
-    """Resize mid-run (shrink 4->2 and grow 2->6) continues bitwise-
-    identically to the fixed-rank reference."""
+def test_elastic_resize_preserves_physics(nranks):
+    """Resize mid-run (shrink 4->2 and grow 2->6) continues the fixed-rank
+    reference to float32 round-off: the codec round-trip is exact, but the
+    resized run steps differently sized per-rank stacks."""
     n0, n1 = nranks
     ref = AMRLBM(_cfg(nranks=n0, stepping_mode="sharded"))
     ref.run(COARSE_STEPS, amr_interval=AMR_INTERVAL)
@@ -132,12 +136,12 @@ def test_elastic_resize_preserves_physics_bitwise(nranks):
     assert owners <= set(range(n1))
     sim.run(COARSE_STEPS - AMR_INTERVAL, amr_interval=AMR_INTERVAL)
 
-    _assert_same_fields(sim, ref, atol=0.0)  # bitwise
+    _assert_same_fields(sim, ref)
 
 
 def test_elastic_resize_via_disk_checkpoint(tmp_path):
     """The durable variant routes the same protocol through the on-disk
-    checkpoint files and stays bitwise too."""
+    checkpoint files and matches the fixed-rank reference the same way."""
     ref = AMRLBM(_cfg(nranks=2, stepping_mode="arena"))
     ref.run(6, amr_interval=AMR_INTERVAL)
 
@@ -146,7 +150,7 @@ def test_elastic_resize_via_disk_checkpoint(tmp_path):
     report = resize_ranks(sim, 3, checkpoint_dir=tmp_path / "ckpt")
     assert report.via_disk
     sim.run(2, amr_interval=AMR_INTERVAL)
-    _assert_same_fields(sim, ref, atol=0.0)
+    _assert_same_fields(sim, ref)
 
 
 def test_service_stream_poll_and_checkpoints(tmp_path):
@@ -205,7 +209,8 @@ def test_pallas_solo_job_matches_fused_reference_bitwise():
     """A ``kernel_backend="pallas"`` job is unbatchable (the ensemble program
     is built from the ref coefficient kernel) and must run solo through its
     own fused engine — submit/poll/stream all work, and the final state is
-    bitwise-identical to an independent fused run of the same config."""
+    bitwise-identical to an independent fused run of the same config (one
+    program, one input: determinism, not conformance)."""
     over = dict(stepping_mode="fused", kernel_backend="pallas")
     cfg = _cfg(**over)
     assert not is_batchable(cfg)
@@ -225,22 +230,10 @@ def test_pallas_solo_job_matches_fused_reference_bitwise():
     job = svc.jobs[jid]
     assert job.status == "done" and job.step == steps
     assert job.sim.amr_cycles >= 1, "the run must cross an AMR event"
-    _assert_same_fields(job.sim, ref, atol=0.0)  # bitwise
+    _assert_same_fields(job.sim, ref, bitwise=True)
 
     s = svc.summary()
     assert s["solo_steps"] == steps and s["ensembles_formed"] == 0
     assert s["compile_misses"] == 0, "solo jobs must not touch the batch cache"
     polled = svc.poll(jid)
     assert polled["status"] == "done" and polled["step"] == steps
-
-
-def test_explicitly_donated_jobs_run_solo_on_cpu():
-    """``donate_pdfs=True`` on XLA:CPU perturbs the solo fused math by one
-    ulp (codegen under aliasing), so such jobs must not join a batch whose
-    program never donates — the per-member bitwise contract would lie."""
-    import jax
-
-    if jax.default_backend() != "cpu":
-        pytest.skip("CPU-only donation-drift gate")
-    assert is_batchable(_cfg(stepping_mode="arena"))
-    assert not is_batchable(_cfg(stepping_mode="arena", donate_pdfs=True))

@@ -325,7 +325,6 @@ def test_fused_sharded_trace_is_valid_and_shows_all_phases(tmp_path):
             cells_per_block=(4, 4, 4),
             nranks=4,
             stepping_mode="fused_sharded",
-            overlap_split=True,
         )
     )
     sim.advance(1)

@@ -217,7 +217,7 @@ def test_device_superstep_compiles_for_four_chips_and_names_its_halo_parts(topo)
     mesh = jax.sharding.Mesh(np.array(topo.devices[:nranks]), ("ranks",))
     counts = padded_block_counts(rank_slots, nranks)
     fn = make_device_superstep(
-        mesh=mesh, levels=(0, 1), plans=plans, schedules=schedules, donate=True,
+        mesh=mesh, levels=(0, 1), plans=plans, schedules=schedules,
         steppers={l: make_stream_collide(backend="pallas", interpret=False, **KW) for l in (0, 1)},
         spec=spec, counts=counts,
     )
